@@ -16,7 +16,6 @@ overlapping costs no extra communication.
 """
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 import textwrap
@@ -24,7 +23,7 @@ import textwrap
 import jax
 import numpy as np
 
-from benchmarks.common import M_CLIENTS, make_problem
+from benchmarks.common import M_CLIENTS, fake_cpu_env, make_problem
 from repro.config import FedConfig
 from repro.core import make_algorithm, run_rounds
 from repro.core.selection import AvailabilityParticipation
@@ -110,9 +109,7 @@ def run():
 
 
 def run_sharded() -> str:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env = fake_cpu_env(8)
     out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)
     assert "ASYNC_SHARDED_OK" in out.stdout, out.stdout + out.stderr

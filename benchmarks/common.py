@@ -7,8 +7,11 @@ the stopping rule evaluated on device; wall times exclude compilation
 """
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.config import FedConfig
 from repro.core import make_algorithm, run_rounds
@@ -20,6 +23,17 @@ M_CLIENTS = 64
 N_DIM = 100
 D_SAMPLES = 6400
 MAX_ROUNDS = 500
+
+
+def fake_cpu_env(num_devices: int = 8) -> dict:
+    """Environment for a child process that runs on `num_devices` fake CPU
+    devices. The child is held to the CPU backend: a parent that has
+    imported JAX may hold the accelerator, and a second process that
+    opens it fails or hangs."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={num_devices}"
+    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    return env
 
 
 def make_problem(name: str, seed: int):
@@ -39,6 +53,18 @@ def make_problem(name: str, seed: int):
         raise KeyError(name)
     batch = {k: jnp.asarray(v) for k, v in raw.items()}
     return model, batch, tol
+
+
+def million_client_batch(m: int, n: int = 32, seed: int = 0) -> dict:
+    """One least-squares sample per client, built directly: the paper's
+    heterogeneous-size splitter (`linreg_noniid`) is O(m^2), which rules
+    it out at m = 10^6. The batch is the only (m, ...) input."""
+    rng = np.random.default_rng(seed)
+    A = rng.standard_normal((m, 1, n)).astype(np.float32)
+    x_star = rng.standard_normal(n).astype(np.float32)
+    b = (A @ x_star + 0.1 * rng.standard_normal((m, 1))).astype(np.float32)
+    return {"A": jnp.asarray(A), "b": jnp.asarray(b),
+            "mask": jnp.ones((m, 1), jnp.float32)}
 
 
 ALGO_HPARAMS = {

@@ -61,10 +61,10 @@ import sys
 import textwrap
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import M_CLIENTS, make_problem
+from benchmarks.common import (M_CLIENTS, fake_cpu_env, make_problem,
+                               million_client_batch)
 from repro.config import FedConfig
 from repro.core import make_algorithm, run_rounds
 from repro.core.selection import AvailabilityParticipation
@@ -213,12 +213,7 @@ def run_active_1m() -> dict:
     from repro.models import LeastSquares
 
     n = 32
-    rng = np.random.default_rng(0)
-    A = rng.standard_normal((M_1M, 1, n)).astype(np.float32)
-    x_star = rng.standard_normal(n).astype(np.float32)
-    b = (A @ x_star + 0.1 * rng.standard_normal((M_1M, 1))).astype(np.float32)
-    batch = {"A": jnp.asarray(A), "b": jnp.asarray(b),
-             "mask": jnp.ones((M_1M, 1), jnp.float32)}
+    batch = million_client_batch(M_1M, n)
     model = LeastSquares(n)
     fed = FedConfig(algorithm="fedavg", num_clients=M_1M, k0=5, lr=0.01)
     algo = make_algorithm(fed, model.loss, model=model)
@@ -252,12 +247,7 @@ def run_offload_1m() -> dict:
     from repro.models import LeastSquares
 
     n = 32
-    rng = np.random.default_rng(0)
-    A = rng.standard_normal((M_1M, 1, n)).astype(np.float32)
-    x_star = rng.standard_normal(n).astype(np.float32)
-    b = (A @ x_star + 0.1 * rng.standard_normal((M_1M, 1))).astype(np.float32)
-    batch = {"A": jnp.asarray(A), "b": jnp.asarray(b),
-             "mask": jnp.ones((M_1M, 1), jnp.float32)}
+    batch = million_client_batch(M_1M, n)
     model = LeastSquares(n)
     fed = FedConfig(algorithm="fedpd", num_clients=M_1M, k0=5, lr=0.05,
                     fedpd_eta=1.0)
@@ -298,9 +288,7 @@ def run_offload_1m() -> dict:
 
 
 def run_sharded(overlap: str = "off") -> float:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env = fake_cpu_env(8)
     out = subprocess.run(
         [sys.executable, "-c",
          _SHARDED_SCRIPT.format(rounds=ROUNDS, overlap=overlap)],

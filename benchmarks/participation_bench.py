@@ -17,7 +17,6 @@ Two parts:
 """
 from __future__ import annotations
 
-import os
 import re
 import subprocess
 import sys
@@ -25,7 +24,7 @@ import textwrap
 
 import jax
 
-from benchmarks.common import M_CLIENTS, make_problem
+from benchmarks.common import M_CLIENTS, fake_cpu_env, make_problem
 from repro.config import FedConfig
 from repro.core import UniformParticipation, make_algorithm, run_rounds
 
@@ -114,9 +113,7 @@ def run():
 
 
 def run_sharded() -> str:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env = fake_cpu_env(8)
     out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)
     assert "PARTICIPATION_SHARDED_OK" in out.stdout, out.stdout + out.stderr

@@ -31,6 +31,7 @@ import time
 from benchmarks import async_bench, engine_bench, fig1_convergence, fig2_k0
 from benchmarks import fig3_alpha, kernels_bench, participation_bench
 from benchmarks import roofline, table4, wallclock_bench
+from repro.utils.compile_cache import use_compile_cache
 
 SECTIONS = {
     "table4": table4.main,
@@ -47,6 +48,7 @@ SECTIONS = {
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", choices=sorted(SECTIONS), default=None,
                     action="append",

@@ -89,7 +89,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import checkpoint as ckpt_io
@@ -109,8 +108,10 @@ class RoundResult:
     # Path-specific diagnostics that are not per-round metrics. The
     # host-offloaded store reports `device_peak_bytes` (XLA
     # memory_analysis of the compiled tile round, when the backend
-    # exposes it) and `host_resident_bytes` (the buffers that left the
-    # device). Empty for the dense/active paths.
+    # exposes it), `host_resident_bytes` (the buffers that left the
+    # device) and where they went (`host_memory_kind`,
+    # `tile_memory_kind`, `host_placement_reason`; see
+    # `_run_offload_loop`). Empty for the dense/active paths.
     extras: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
@@ -357,9 +358,9 @@ def make_round_fn(algo, mesh=None, client_axis="data",
             out_specs = (_state_specs(algo, abs_state, client_axis),
                          jax.tree.map(lambda l: _full_spec(None, l.ndim),
                                       abs_met))
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )(state, batch, *extra)
 
     return sharded_round
@@ -1443,7 +1444,13 @@ def _run_offload_loop(algo, state, batch, num_rounds, tol, tol_metric,
     warm-up convention); the compiled tile round's `memory_analysis`
     (where the backend exposes it) is reported as
     ``RoundResult.extras["device_peak_bytes"]`` next to
-    ``host_resident_bytes``.
+    ``host_resident_bytes``. ``extras`` also names where the store went:
+    ``host_memory_kind`` of the resident buffers after the last round
+    (`pt.memory_kind`,
+    e.g. ``tpu:pinned_host`` or ``cpu:device``), ``tile_memory_kind`` of
+    a host-gathered tile (participant tiles only) and
+    ``host_placement_reason``, why the store is not in pinned host
+    memory (None when it is).
     """
     population = getattr(algo, "active_tile", "participants") == "population"
     client_keys = tuple(k for k in getattr(algo, "flat_client_keys", ())
@@ -1541,7 +1548,8 @@ def _run_offload_loop(algo, state, batch, num_rounds, tol, tol_metric,
         sl_abs).compile()
 
     extras = {"host_resident_bytes": int(host_bytes),
-              "device_peak_bytes": None}
+              "device_peak_bytes": None,
+              "host_placement_reason": pt.host_placement_reason()}
     ma_fn = getattr(round_c, "memory_analysis", None)
     if ma_fn is not None:
         try:
@@ -1607,7 +1615,10 @@ def _run_offload_loop(algo, state, batch, num_rounds, tol, tol_metric,
         idx_h, staged = None, batch_dev
     else:
         idx_h = pt.host_put(idx)
-        staged = to_dev(gather_h(batch_h, idx_h))
+        tile_h = gather_h(batch_h, idx_h)
+        extras["tile_memory_kind"] = pt.memory_kind(
+            jax.tree.leaves(tile_h)[0])
+        staged = to_dev(tile_h)
     t0 = time.time()
     for i in range(start_round, num_rounds):
         if population:
@@ -1683,6 +1694,11 @@ def _run_offload_loop(algo, state, batch, num_rounds, tol, tol_metric,
                 (jax.device_get(ckpt_tree(pcs_prev)), hist_np),
                 extra={"fingerprint": fingerprint})
     wall = time.time() - t0
+    # where the resident buffers ARE after the last round's scatter
+    resident = (list(store.buffers.values()) + jax.tree.leaves(batch_h)
+                + ([anchor_h] if async_rounds else []))
+    extras["host_memory_kind"] = (pt.memory_kind(resident[0])
+                                  if resident else None)
     state_f = dict(gstate)
     for k, b in store.buffers.items():
         state_f[k] = jax.device_put(b, dev)

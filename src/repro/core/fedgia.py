@@ -37,6 +37,8 @@ from repro.core.api import LossFn, broadcast_clients, per_client_value_and_grad
 from repro.kernels.fedgia_update import fedgia_update_flat, kernel_by_default
 from repro.utils import pytree as pt
 
+LIPSCHITZ_BLOCK = 4096  # clients per vmapped block of the init-time r_i
+
 
 class FedGiA:
     name = "fedgia"
@@ -78,7 +80,10 @@ class FedGiA:
             )
             r = per_client(init_batch, jax.random.split(rng, m)).max()
         elif self.model is not None and hasattr(self.model, "lipschitz") and init_batch is not None:
-            r = jax.vmap(self.model.lipschitz)(init_batch).max()
+            # in blocks of clients: one vmap over m=10^6 per-client
+            # spectral norms needs tens of GB of SVD temporaries
+            r = jax.lax.map(self.model.lipschitz, init_batch,
+                            batch_size=LIPSCHITZ_BLOCK).max()
 
         # paper §V.B: x_i^0 = pi_i^0 = 0; we start from params0 instead of 0
         # so the same init works for NNs (paper setting recovered with

@@ -29,6 +29,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 512  # (512, 128) fp32 = 256 KiB per operand block in VMEM
+SEL_BLOCK = 1024  # clients per SMEM block of the batched branch select:
+#                   int32, 4 KiB of the 1 MiB SMEM
 
 
 def _kernel(sel_ref, scal_ref, xbar_ref, g_ref, pi_ref, h_ref,
@@ -62,9 +64,10 @@ def _batched_kernel(sel_ref, scal_ref, xbar_ref, g_ref, pi_ref, h_ref,
     """One (client, row-block) grid step of the batched round update.
 
     Identical math to `_kernel`, but the client index is grid dimension 0
-    and the per-client ADMM/GD branch select comes from the (m,) SMEM
-    `sel_ref` — the whole round's client axis runs in ONE pallas_call
-    instead of m dispatches."""
+    and the per-client ADMM/GD branch select comes from the
+    (SEL_BLOCK,) SMEM block of the (m,) select vector that holds this
+    step's client — the whole round's client axis runs in ONE
+    pallas_call instead of m dispatches."""
     i = pl.program_id(0)
     sigma = scal_ref[0]
     inv_m = scal_ref[1]
@@ -80,7 +83,7 @@ def _batched_kernel(sel_ref, scal_ref, xbar_ref, g_ref, pi_ref, h_ref,
     pi_admm = ak1 * a * base - g
     x_admm = xbar - d * ak1 * base
 
-    is_sel = sel_ref[i] > 0
+    is_sel = sel_ref[i % SEL_BLOCK] > 0
     x_new = jnp.where(is_sel, x_admm, xbar)
     pi_new = jnp.where(is_sel, pi_admm, -g)
     z_new = x_new + pi_new / sigma
@@ -111,15 +114,19 @@ def _batched_call(xbar, gbar, pi, h, sel, sigma, m, *, k0: int,
         return v.reshape(mb, rows, LANES)
 
     scal = jnp.stack([sigma.astype(jnp.float32), jnp.float32(1.0 / m)])
-    sel_arr = sel.astype(jnp.int32)
 
     block = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
     rep = pl.BlockSpec(memory_space=pltpu.SMEM)
+    # the select vector enters SMEM one SEL_BLOCK of clients at a time:
+    # the whole (mb,) int32 vector exceeds the 1 MiB SMEM from mb ~ 2.6e5
+    sel_arr = jnp.pad(sel.astype(jnp.int32), (0, (-mb) % SEL_BLOCK))
+    sel_block = pl.BlockSpec((SEL_BLOCK,), lambda i, j: (i // SEL_BLOCK,),
+                             memory_space=pltpu.SMEM)
     out_shape = [jax.ShapeDtypeStruct((mb, rows, LANES), xbar.dtype)] * 3
     x_new, pi_new, z_new = pl.pallas_call(
         functools.partial(_batched_kernel, k0=k0),
         grid=grid,
-        in_specs=[rep, rep, block, block, block, block],
+        in_specs=[sel_block, rep, block, block, block, block],
         out_specs=[block, block, block],
         out_shape=out_shape,
         input_output_aliases=_DONATE_ALIASES if donate else {},

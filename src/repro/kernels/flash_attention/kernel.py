@@ -27,11 +27,6 @@ NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x releases;
-# accept either so the kernel imports on both sides of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             scale: float, block_q: int, block_k: int, seq_len: int,
             window, causal: bool):
@@ -145,7 +140,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -24,11 +24,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_T = 64
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x releases;
-# accept either so the kernel imports on both sides of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sfin_ref, s_scr, *,
             block_t: int, seq_len: int):
     ti = pl.program_id(2)
@@ -91,7 +86,7 @@ def rwkv6_scan_kernel(r, k, v, w, u, *, block_t: int = DEFAULT_BLOCK_T,
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent for every
 (architecture x input shape x mesh) with abstract lowering + compile only.
 
@@ -20,7 +16,16 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b \
       --shape train_4k [--multi-pod] [--algo fedgia|fedavg] [--unrolled]
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
+
+Run as a script it gives itself 512 fake CPU devices; imported, it leaves
+the process's devices alone.
 """
+import os
+
+if __name__ == "__main__":
+    # before jax is imported: the flag is read once, at backend start-up
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import dataclasses
 import json

@@ -6,13 +6,11 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: `axis_types` appeared after
-    0.4.37 (and AxisType.Auto is its default); pass it only when it exists."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with Auto axes: the engine's `shard_map` rounds and
+    `NamedSharding` placements assume GSPMD propagation, and make_mesh
+    defaults to Explicit axes."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -22,6 +22,7 @@ from repro.configs import get_config, list_architectures
 from repro.core.engine import scan_steps
 from repro.models import Transformer
 from repro.utils import get_logger
+from repro.utils.compile_cache import use_compile_cache
 
 log = get_logger("serve")
 
@@ -92,6 +93,7 @@ def serve(args):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_architectures(), required=True)
     ap.add_argument("--reduced", action="store_true")

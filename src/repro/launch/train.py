@@ -110,6 +110,7 @@ from repro.models import (
     Transformer,
 )
 from repro.utils import get_logger
+from repro.utils.compile_cache import use_compile_cache
 
 log = get_logger("train")
 
@@ -483,8 +484,9 @@ def validate_flags(args) -> dict:
     }
 
 
-def train(args) -> dict:
-    parsed = validate_flags(args)
+def build_algorithm(args, parsed: dict):
+    """The algorithm, initial state and batch that `train(args)` runs;
+    `parsed` is `validate_flags(args)`."""
     model, loss_fn, params0, batch = build_problem(args)
     fed = FedConfig(
         algorithm=args.algo,
@@ -501,6 +503,12 @@ def train(args) -> dict:
     )
     algo = make_algorithm(fed, loss_fn, model=model)
     state = algo.init(params0, jax.random.PRNGKey(args.seed + 1), init_batch=batch)
+    return algo, state, batch
+
+
+def train(args) -> dict:
+    parsed = validate_flags(args)
+    algo, state, batch = build_algorithm(args, parsed)
 
     # engine knobs default off so programmatic callers can pass a bare
     # Namespace with only the legacy fields
@@ -960,6 +968,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main():
+    use_compile_cache()
     train(build_parser().parse_args())
 
 

@@ -7,11 +7,20 @@ model exposes the same protocol as Transformer.loss: loss(params, batch) ->
 
 Losses follow the paper's normalisation: per-client
   f_i(x) = (1/d_i) sum_j loss_j  (+ regulariser / d_i)
+
+Every product runs at full f32 precision (`_mm`). A TPU's default
+precision multiplies f32 operands in one bf16 pass, and the gradient
+noise that leaves puts ||grad f||^2 above the eq. (35) tolerance of the
+logistic problems: the run never stops. The CPU computes f32 either way.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+_mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
 
 class LeastSquares:
@@ -26,7 +35,7 @@ class LeastSquares:
     def loss(self, params, batch):
         A, b = batch["A"], batch["b"]
         mask = batch.get("mask")
-        r = A @ params["x"] - b
+        r = _mm(A, params["x"]) - b
         if mask is None:
             loss = 0.5 * jnp.mean(jnp.square(r))
         else:
@@ -36,7 +45,7 @@ class LeastSquares:
     def gram(self, batch):
         """H_i = B_i / d_i with B_i = A_i^T A_i (paper Table III, Ex. V.1)."""
         A, d = _masked(batch)
-        return (A.T @ A) / d
+        return _mm(A.T, A) / d
 
     def lipschitz(self, batch):
         """r_i = ||B_i|| / d_i (spectral norm of the Hessian)."""
@@ -67,7 +76,7 @@ class LogisticRegression:
     def loss(self, params, batch):
         A, b = batch["A"], batch["b"]
         mask = batch.get("mask")
-        z = A @ params["x"]
+        z = _mm(A, params["x"])
         per = jnp.logaddexp(0.0, z) - b * z
         if mask is None:
             d = A.shape[0]
@@ -82,7 +91,7 @@ class LogisticRegression:
     def gram(self, batch):
         """H_i = B_i/(4 d_i) (paper Table III, Ex. V.2): sigmoid' <= 1/4."""
         A, d = _masked(batch)
-        return (A.T @ A) / (4.0 * d)
+        return _mm(A.T, A) / (4.0 * d)
 
     def lipschitz(self, batch):
         _, d = _masked(batch)
@@ -104,7 +113,7 @@ class NonConvexLogistic:
         A, b = batch["A"], batch["b"]
         mask = batch.get("mask")
         x = params["x"]
-        z = A @ x
+        z = _mm(A, x)
         per = jnp.logaddexp(0.0, z) - b * z
         if mask is None:
             d = A.shape[0]
@@ -120,7 +129,7 @@ class NonConvexLogistic:
     def gram(self, batch):
         """Paper Table III, Ex. V.3: B_i/(4 d_i) + mu I / d_i."""
         A, d = _masked(batch)
-        return (A.T @ A) / (4.0 * d) + self.mu * jnp.eye(self.n) / d
+        return _mm(A.T, A) / (4.0 * d) + self.mu * jnp.eye(self.n) / d
 
     def lipschitz(self, batch):
         return jnp.linalg.norm(self.gram(batch), ord=2)

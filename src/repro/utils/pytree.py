@@ -400,82 +400,69 @@ def scatter_rows(buf: jax.Array, idx: jax.Array, rows: jax.Array) -> jax.Array:
 # each round. Placement preference: pinned host memory of the default
 # device (sharding memory_kind="pinned_host", zero-copy DMA on TPU/GPU)
 # when the backend both accepts it AND can run the row gather/scatter on
-# it; otherwise the CPU backend's device. On a CPU-only process the two
-# coincide and every transfer below is a no-op.
+# it; otherwise the CPU backend's device, with the reason recorded. On a
+# CPU-only process the two coincide and every transfer below is a no-op.
 
-_HOST_PLACEMENT = None
+_HOST_PLACEMENT = None  # (placement, reason it is not pinned host or None)
 
 
 def host_placement():
     """The device/sharding host-resident offload buffers are committed
     to. Probed once per process; the probe runs the exact ops the
     offload store needs (row take / indexed set), so a backend that
-    merely *stores* pinned-host arrays but cannot compute on them falls
-    back to the CPU device."""
+    merely *stores* pinned-host arrays but cannot compute on them, or
+    computes into device memory, gets the CPU device instead. That
+    choice is never silent: it warns, and :func:`host_placement_reason`
+    says why (the offload loop reports it in ``RoundResult.extras``)."""
     global _HOST_PLACEMENT
     if _HOST_PLACEMENT is not None:
-        return _HOST_PLACEMENT
-    placement = None
+        return _HOST_PLACEMENT[0]
+    placement, reason = None, "the default backend is the CPU"
     if jax.default_backend() != "cpu":
         try:
             sharding = jax.sharding.SingleDeviceSharding(
                 jax.devices()[0], memory_kind="pinned_host")
-            probe = jax.device_put(jnp.zeros((2, 2), jnp.float32), sharding)
-            idx = jax.device_put(jnp.zeros((1,), jnp.int32), sharding)
-            out = probe.at[idx].set(
-                jnp.take(probe, idx, axis=0, mode="clip"), mode="drop")
+            # several rows: a one-row take lowers to a dynamic slice,
+            # which compiles where the store's row gather does not
+            probe = jax.device_put(jnp.zeros((4, 1, 8), jnp.float32),
+                                   sharding)
+            idx = jax.device_put(jnp.arange(3, dtype=jnp.int32), sharding)
+            out = scatter_rows(probe, idx, gather_rows(probe, idx))
             jax.block_until_ready(out)
-            placement = sharding
-        except Exception:
-            placement = None
+            if out.sharding.memory_kind != "pinned_host":
+                # the row ops would move the store into device memory
+                raise RuntimeError("row ops on pinned_host arrays return "
+                                   f"{out.sharding.memory_kind} memory")
+            placement, reason = sharding, None
+        except Exception as exc:  # XlaRuntimeError has no stable subclass
+            reason = f"pinned_host probe failed: {type(exc).__name__}: {exc}"
+            warnings.warn(
+                f"offload store placed on the CPU backend ({reason})",
+                RuntimeWarning, stacklevel=2)
     if placement is None:
         placement = jax.local_devices(backend="cpu")[0]
-    _HOST_PLACEMENT = placement
+    _HOST_PLACEMENT = (placement, reason)
     return placement
 
 
-def _demote_to_cpu():
-    """Permanently demote the process-wide host placement to the CPU
-    backend (the always-works path the init-time probe falls back to)."""
-    global _HOST_PLACEMENT
-    _HOST_PLACEMENT = jax.local_devices(backend="cpu")[0]
-    return _HOST_PLACEMENT
+def host_placement_reason():
+    """Why the offload store is not in pinned host memory, or None when
+    it is."""
+    host_placement()
+    return _HOST_PLACEMENT[1]
+
+
+def memory_kind(x: jax.Array) -> str:
+    """``<platform>:<memory kind>`` of an array, e.g. ``tpu:pinned_host``
+    or ``cpu:device``."""
+    dev = next(iter(x.devices()))
+    return f"{dev.platform}:{x.sharding.memory_kind}"
 
 
 def host_put(x) -> jax.Array:
-    """Commit an array to the offload store's host placement.
-
-    Hardened against MID-RUN transfer failures: the pinned-host pool can
-    exhaust or the DMA path can error long after the init-time probe in
-    :func:`host_placement` succeeded (e.g. another process grabbed the
-    pinned pool, or a transient driver hiccup). A failed transfer is
-    retried once with a warning; a second failure demotes the placement
-    to the CPU backend for the remainder of the process instead of
-    crashing the run — gather/scatter semantics are identical there
-    (same clip/drop row ops, bit-identical values), only the transfer
-    path is slower.
-    """
-    placement = host_placement()
-    try:
-        return jax.device_put(x, placement)
-    except Exception as exc:  # XlaRuntimeError has no stable subclass
-        warnings.warn(
-            "host_put: transfer to the offload host placement failed "
-            f"({type(exc).__name__}: {exc}); retrying once",
-            RuntimeWarning, stacklevel=2)
-    try:
-        return jax.device_put(x, placement)
-    except Exception as exc:
-        if not isinstance(placement, jax.sharding.Sharding):
-            # Already on the CPU-device fallback: nothing left to demote
-            # to — this is a real error, surface it.
-            raise
-        warnings.warn(
-            "host_put: pinned-host transfer failed twice "
-            f"({type(exc).__name__}: {exc}); falling back to the CPU "
-            "backend for the remainder of the run",
-            RuntimeWarning, stacklevel=2)
-        return jax.device_put(x, _demote_to_cpu())
+    """Commit an array to the offload store's host placement. A failed
+    transfer raises: the run does not carry on with its store moved."""
+    return jax.device_put(x, host_placement())
 
 
 def host_put_tree(tree: Pytree) -> Pytree:

@@ -31,11 +31,13 @@ except ImportError:  # hypothesis is optional: its tests importorskip
 
 def fake_device_env(num_devices: int = 8) -> dict:
     """Environment for a subprocess that should see `num_devices` fake CPU
-    devices: XLA_FLAGS set before jax import, PYTHONPATH pointing at src
-    AND at this directory (the scripts import the shared `hlo_guard`
-    collective classifier)."""
+    devices: held to the CPU backend (the test process may hold the
+    accelerator, which a second process cannot open), XLA_FLAGS set before
+    jax import, PYTHONPATH pointing at src AND at this directory (the
+    scripts import the shared `hlo_guard` collective classifier)."""
     tests_dir = os.path.dirname(os.path.abspath(__file__))
-    env = dict(os.environ, PYTHONPATH=SRC + os.pathsep + tests_dir)
+    env = dict(os.environ, PYTHONPATH=SRC + os.pathsep + tests_dir,
+               JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={num_devices}"
     return env
 

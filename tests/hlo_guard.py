@@ -63,6 +63,20 @@ def model_size_all_reduces(txt: str) -> int:
     return collective_counts(txt)["all-reduce"]
 
 
+_LOCATION_SECTIONS = ("FileNames", "FunctionNames", "FileLocations",
+                      "StackFrames")
+
+
+def program_text(txt: str) -> str:
+    """Compiled HLO text with the source-location side tables, the
+    per-op `metadata={...}` and the module header line removed: two
+    programs that compute the same thing from different call sites
+    compare equal, while any changed instruction still shows."""
+    blocks = [b for b in txt.split("\n\n")
+              if not b.startswith(("HloModule",) + _LOCATION_SECTIONS)]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n\n".join(blocks))
+
+
 def assert_barrier_round(txt: str, label: str = "") -> None:
     """The one-psum round (PR-5): exactly ONE model-size all-reduce, no
     all-gather. XLA additionally lowers the shard-local diagnostics

@@ -10,7 +10,7 @@ from repro.kernels.fedgia_update import (
     fedgia_update_flat,
     fedgia_update_ref,
 )
-from repro.kernels.fedgia_update.kernel import LANES
+from repro.kernels.fedgia_update.kernel import LANES, SEL_BLOCK
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
 from repro.kernels.rwkv6_scan import rwkv6_scan, rwkv6_scan_ref
 
@@ -90,6 +90,25 @@ def test_fedgia_update_batched_matches_ref(n, k0):
                              use_kernel=True, interpret=True)
     for a, b, name in zip(out, ref, ("x", "pi", "z")):
         assert a.shape == (m, n), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+def test_fedgia_update_batched_sel_spans_smem_blocks():
+    """The branch select enters SMEM SEL_BLOCK clients at a time: a client
+    count that spans two full blocks and a ragged third must still read
+    each client's own select bit."""
+    m, n = 2 * SEL_BLOCK + 5, LANES
+    xbar, g, pi = (jnp.asarray(RNG.standard_normal((m, n)), jnp.float32)
+                   for _ in range(3))
+    h = jnp.asarray(RNG.uniform(0.05, 3.0, (m, n)), jnp.float32)
+    sel = jnp.asarray(RNG.random(m) < 0.5)
+    sigma = jnp.float32(0.7)
+    ref = fedgia_update_flat(xbar, g, pi, h, sel, sigma, m, k0=3,
+                             use_kernel=False)
+    out = fedgia_update_flat(xbar, g, pi, h, sel, sigma, m, k0=3,
+                             use_kernel=True, interpret=True)
+    for a, b, name in zip(out, ref, ("x", "pi", "z")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-5, err_msg=name)
 

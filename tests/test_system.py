@@ -8,20 +8,17 @@ import numpy as np
 import pytest
 
 
-def test_train_driver_linreg_end_to_end():
-    from repro.launch.train import main as train_main
+def test_train_driver_linreg_end_to_end(monkeypatch):
+    from repro.launch import train as train_mod
 
-    argv = [
+    # the persistent compile cache is the caller's to place, not a test's
+    monkeypatch.setattr(train_mod, "use_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
         "train", "--problem", "linreg", "--algo", "fedgia", "--clients", "16",
         "--k0", "5", "--rounds", "100", "--dim", "40", "--samples", "1600",
         "--tol", "1e-9",
-    ]
-    old = sys.argv
-    sys.argv = argv
-    try:
-        train_main()
-    finally:
-        sys.argv = old
+    ])
+    train_mod.main()
 
 
 def test_train_driver_transformer_loss_improves(tmp_path):
