@@ -79,6 +79,7 @@ why scan == legacy holds exactly for every feature combination.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -383,6 +384,7 @@ def shard_inputs(algo, state, batch, mesh, client_axis: str = "data"):
 AUTO_CHUNK_CANDIDATES = (8, 32, 128)
 
 
+@functools.partial(jax.profiler.annotate_function, name="run_rounds")
 def run_rounds(
     algo,
     state,
@@ -631,469 +633,497 @@ def run_rounds(
     resolves by backend like `donate`: enabled off-CPU, disabled on CPU
     (CPU XLA cannot alias, and the CPU Pallas path is interpret-only).
     Ignored by algorithms without a kernel path.
+
+    Host spans (`jax.profiler.TraceAnnotation`, on the profiler's clock
+    with the device's ops): `run_rounds` covers the call;
+    `run_rounds.prepare` the checks, ravel, state copy and carry up to
+    the first chunk program; `run_rounds.lower` and `run_rounds.compile`
+    each AOT-compiled chunk length; `run_rounds.fetch` the history and
+    state brought back after the last chunk. The chunk loop, where the
+    device works, lies outside the phase spans. On the legacy and
+    offload loops only `run_rounds` and `run_rounds.prepare` are
+    recorded.
     """
-    if num_rounds <= 0:
-        return RoundResult(state, {}, 0, False, 0.0)
-    auto_chunk = isinstance(chunk_size, str)
-    if auto_chunk:
-        if chunk_size != "auto":
+    with contextlib.ExitStack() as prepare:
+        prepare.enter_context(
+            jax.profiler.TraceAnnotation("run_rounds.prepare"))
+        if num_rounds <= 0:
+            return RoundResult(state, {}, 0, False, 0.0)
+        auto_chunk = isinstance(chunk_size, str)
+        if auto_chunk:
+            if chunk_size != "auto":
+                raise ValueError(
+                    f"chunk_size must be an int or 'auto', got {chunk_size!r}")
+            if not scan:
+                raise ValueError(
+                    "chunk_size='auto' tunes the scan chunk length — the "
+                    "legacy per-round loop (scan=False) has no chunks")
+            if mesh is not None:
+                # chunks compile lazily under a mesh (GSPMD may re-place carry
+                # leaves between chunks, so there is no AOT warm-up) — the
+                # candidate timings would measure compilation, not rounds
+                raise ValueError(
+                    "chunk_size='auto' needs AOT-precompiled candidates to "
+                    "time execution, which the sharded path does not have — "
+                    "pass a fixed chunk_size under a mesh")
+        if clock is not None:
+            if participation is not None:
+                raise ValueError(
+                    "clock= and participation= are mutually exclusive: the "
+                    "clock DERIVES the arrival mask from simulated finish "
+                    "times (core/clock.py), a policy samples it"
+                )
+            if clock.m != algo.fed.num_clients:
+                raise ValueError(
+                    f"clock models {clock.m} clients, algorithm has "
+                    f"{algo.fed.num_clients}"
+                )
+            async_rounds = True  # a clock IS an arrival process
+        if stale_weighting not in api.STALE_WEIGHTINGS:
             raise ValueError(
-                f"chunk_size must be an int or 'auto', got {chunk_size!r}")
-        if not scan:
-            raise ValueError(
-                "chunk_size='auto' tunes the scan chunk length — the "
-                "legacy per-round loop (scan=False) has no chunks")
-        if mesh is not None:
-            # chunks compile lazily under a mesh (GSPMD may re-place carry
-            # leaves between chunks, so there is no AOT warm-up) — the
-            # candidate timings would measure compilation, not rounds
-            raise ValueError(
-                "chunk_size='auto' needs AOT-precompiled candidates to "
-                "time execution, which the sharded path does not have — "
-                "pass a fixed chunk_size under a mesh")
-    if clock is not None:
-        if participation is not None:
-            raise ValueError(
-                "clock= and participation= are mutually exclusive: the "
-                "clock DERIVES the arrival mask from simulated finish "
-                "times (core/clock.py), a policy samples it"
+                f"unknown stale_weighting {stale_weighting!r}: "
+                f"{api.STALE_WEIGHTINGS}"
             )
-        if clock.m != algo.fed.num_clients:
+        if stale_weighting != "uniform" and not async_rounds:
             raise ValueError(
-                f"clock models {clock.m} clients, algorithm has "
-                f"{algo.fed.num_clients}"
+                "stale_weighting only applies to async rounds — pass "
+                "async_rounds=True (with a participation policy) or clock="
             )
-        async_rounds = True  # a clock IS an arrival process
-    if stale_weighting not in api.STALE_WEIGHTINGS:
-        raise ValueError(
-            f"unknown stale_weighting {stale_weighting!r}: "
-            f"{api.STALE_WEIGHTINGS}"
-        )
-    if stale_weighting != "uniform" and not async_rounds:
-        raise ValueError(
-            "stale_weighting only applies to async rounds — pass "
-            "async_rounds=True (with a participation policy) or clock="
-        )
-    masked = participation is not None or clock is not None
-    if async_rounds:
-        if not masked:
+        masked = participation is not None or clock is not None
+        if async_rounds:
+            if not masked:
+                raise ValueError(
+                    "async_rounds requires an arrival process — a "
+                    "participation policy (e.g. "
+                    "selection.AvailabilityParticipation) or a clock "
+                    "(core.clock.ComputeClock)"
+                )
+            if max_staleness < 0:
+                raise ValueError(
+                    f"max_staleness must be >= 0, got {max_staleness}")
+            if "x" not in state:
+                raise ValueError(
+                    "async_rounds needs the global anchor under state['x'] "
+                    "(FederatedAlgorithm state contract)"
+                )
+        flat = flat and hasattr(algo, "round_flat")
+        if overlap not in ("off", "scatter"):
             raise ValueError(
-                "async_rounds requires an arrival process — a participation "
-                "policy (e.g. selection.AvailabilityParticipation) or a "
-                "clock (core.clock.ComputeClock)"
-            )
-        if max_staleness < 0:
-            raise ValueError(f"max_staleness must be >= 0, got {max_staleness}")
-        if "x" not in state:
+                f"unknown overlap {overlap!r}: ('off', 'scatter')")
+        if overlap == "scatter" and not flat:
             raise ValueError(
-                "async_rounds needs the global anchor under state['x'] "
-                "(FederatedAlgorithm state contract)"
-            )
-    flat = flat and hasattr(algo, "round_flat")
-    if overlap not in ("off", "scatter"):
-        raise ValueError(f"unknown overlap {overlap!r}: ('off', 'scatter')")
-    if overlap == "scatter" and not flat:
-        raise ValueError(
-            "overlap='scatter' splits the flat comm buffer's collective — "
-            "it requires the flat round path (flat=True on an algorithm "
-            "providing round_flat; drop --no-flat)")
-    if donate_kernel is None:
-        # same backend rule as carry donation: CPU XLA cannot alias
-        # buffers (and the CPU Pallas path is interpret-only)
-        donate_kernel = jax.default_backend() != "cpu"
-    if store not in ("dense", "active", "offload"):
-        raise ValueError(
-            f"unknown store {store!r}: ('dense', 'active', 'offload')")
-    active_capacity = None
-    if store in ("active", "offload"):
-        if not flat:
+                "overlap='scatter' splits the flat comm buffer's collective "
+                "— it requires the flat round path (flat=True on an "
+                "algorithm providing round_flat; drop --no-flat)")
+        if donate_kernel is None:
+            # same backend rule as carry donation: CPU XLA cannot alias
+            # buffers (and the CPU Pallas path is interpret-only)
+            donate_kernel = jax.default_backend() != "cpu"
+        if store not in ("dense", "active", "offload"):
             raise ValueError(
-                f"store={store!r} packs the flat (m, N) client buffers — it "
+                f"unknown store {store!r}: ('dense', 'active', 'offload')")
+        active_capacity = None
+        if store in ("active", "offload"):
+            if not flat:
+                raise ValueError(
+                    f"store={store!r} packs the flat (m, N) client buffers — "
+                    "it requires the flat round path (flat=True on an "
+                    "algorithm providing round_flat; drop --no-flat)"
+                )
+            if not masked:
+                raise ValueError(
+                    f"store={store!r} needs a per-round participant set to "
+                    "pack the tile from — pass participation= "
+                    "(core.selection) or clock= (core.clock)"
+                )
+            if not hasattr(algo, "round_flat_active"):
+                raise ValueError(
+                    f"algorithm {getattr(algo, 'name', algo)!r} does not "
+                    "implement round_flat_active"
+                )
+            active_capacity = (algo.fed.num_clients if clock is not None
+                               else participation.active_capacity)
+        if store == "offload":
+            if mesh is not None:
+                raise ValueError(
+                    "store='offload' is the single-device host/device split "
+                    "— under a mesh the resident buffers are already sharded "
+                    "over devices; pass store='active' instead"
+                )
+            if overlap != "off":
+                raise ValueError(
+                    "store='offload' runs the host-driven tile loop — the "
+                    "overlapped-collective carry slot (overlap='scatter') "
+                    "does not ride it"
+                )
+            if auto_chunk:
+                raise ValueError(
+                    "chunk_size='auto' tunes the scan chunk length — the "
+                    "host-driven offload loop (store='offload') has no chunks"
+                )
+        if aggregate not in ("dense", "packed"):
+            raise ValueError(
+                f"unknown aggregate {aggregate!r}: ('dense', 'packed')")
+        if aggregate == "packed" and store == "dense":
+            raise ValueError(
+                "aggregate='packed' sums the packed participant tile — it "
+                "requires store='active' or store='offload'")
+        compressor = compress.as_compressor(
+            compression, error_feedback=error_feedback, topk_frac=topk_frac)
+        # the clock prices the wire the codec actually produces, even when
+        # the identity codec is resolved away below
+        wire_comp = compressor
+        if compressor is not None and compressor.identity \
+                and not compressor.error_feedback:
+            # bitwise escape: the identity codec without error feedback IS
+            # the uncompressed round — resolve to the same lowered program,
+            # not merely the same values
+            compressor = None
+        if compressor is not None and not flat:
+            raise ValueError(
+                "compression operates on the flat (m, N) comm buffer — it "
                 "requires the flat round path (flat=True on an algorithm "
                 "providing round_flat; drop --no-flat)"
             )
-        if not masked:
+        if (faults is not None or screening is not None) and not flat:
             raise ValueError(
-                f"store={store!r} needs a per-round participant set to pack "
-                "the tile from — pass participation= (core.selection) or "
-                "clock= (core.clock)"
+                "faults/screening operate on the flat (m, N) comm buffer — "
+                "they require the flat round path (flat=True on an algorithm "
+                "providing round_flat; drop --no-flat)"
             )
-        if not hasattr(algo, "round_flat_active"):
+        if faults is not None and faults.num_clients != algo.fed.num_clients:
             raise ValueError(
-                f"algorithm {getattr(algo, 'name', algo)!r} does not "
-                "implement round_flat_active"
-            )
-        active_capacity = (algo.fed.num_clients if clock is not None
-                           else participation.active_capacity)
-    if store == "offload":
-        if mesh is not None:
-            raise ValueError(
-                "store='offload' is the single-device host/device split — "
-                "under a mesh the resident buffers are already sharded "
-                "over devices; pass store='active' instead"
-            )
-        if overlap != "off":
-            raise ValueError(
-                "store='offload' runs the host-driven tile loop — the "
-                "overlapped-collective carry slot (overlap='scatter') "
-                "does not ride it"
-            )
-        if auto_chunk:
-            raise ValueError(
-                "chunk_size='auto' tunes the scan chunk length — the "
-                "host-driven offload loop (store='offload') has no chunks"
-            )
-    if aggregate not in ("dense", "packed"):
-        raise ValueError(
-            f"unknown aggregate {aggregate!r}: ('dense', 'packed')")
-    if aggregate == "packed" and store == "dense":
-        raise ValueError(
-            "aggregate='packed' sums the packed participant tile — it "
-            "requires store='active' or store='offload'")
-    compressor = compress.as_compressor(
-        compression, error_feedback=error_feedback, topk_frac=topk_frac)
-    # the clock prices the wire the codec actually produces, even when
-    # the identity codec is resolved away below
-    wire_comp = compressor
-    if compressor is not None and compressor.identity \
-            and not compressor.error_feedback:
-        # bitwise escape: the identity codec without error feedback IS
-        # the uncompressed round — resolve to the same lowered program,
-        # not merely the same values
-        compressor = None
-    if compressor is not None and not flat:
-        raise ValueError(
-            "compression operates on the flat (m, N) comm buffer — it "
-            "requires the flat round path (flat=True on an algorithm "
-            "providing round_flat; drop --no-flat)"
-        )
-    if (faults is not None or screening is not None) and not flat:
-        raise ValueError(
-            "faults/screening operate on the flat (m, N) comm buffer — "
-            "they require the flat round path (flat=True on an algorithm "
-            "providing round_flat; drop --no-flat)"
-        )
-    if faults is not None and faults.num_clients != algo.fed.num_clients:
-        raise ValueError(
-            f"fault model covers {faults.num_clients} clients, algorithm "
-            f"has {algo.fed.num_clients}")
-    if quorum:
-        if not 0 < quorum <= algo.fed.num_clients:
-            raise ValueError(
-                f"quorum must be in [0, m={algo.fed.num_clients}], "
-                f"got {quorum}")
-        if not masked and faults is None and screening is None:
-            raise ValueError(
-                "quorum needs a source of non-arrival to guard against — "
-                "pass participation=, clock=, faults= or screening="
-            )
-    deadline_clock = (clock is not None
-                      and getattr(clock, "deadline_s", None) is not None)
-    if deadline_clock and quorum < 1:
-        raise ValueError(
-            "a deadline clock (ComputeClock(deadline_s=)) can cut rounds "
-            "with ZERO arrivals — pass quorum >= 1 so they degrade to "
-            "recorded no-ops instead of a 0-client mean"
-        )
-    if watchdog:
-        if watchdog_patience < 1:
-            raise ValueError(
-                f"watchdog_patience must be >= 1, got {watchdog_patience}")
-        if watchdog_factor <= 1.0:
-            raise ValueError(
-                "watchdog_factor must be > 1 (a divergence threshold "
-                f"RELATIVE to the best f̄ seen), got {watchdog_factor}")
-        if store == "offload":
-            raise ValueError(
-                "the watchdog keeps a full state snapshot in the carry — "
-                "under store='offload' that would double the host-resident "
-                "buffers; run the watchdog with store='dense'/'active'"
-            )
-    if checkpoint_every < 0:
-        raise ValueError(
-            f"checkpoint_every must be >= 0, got {checkpoint_every}")
-    ckpt_on = checkpoint_every > 0 or resume
-    if ckpt_on:
-        if checkpoint_dir is None:
-            raise ValueError(
-                "checkpoint_every/resume need a checkpoint_dir= to write "
-                "to / restore from")
-        if mesh is not None:
-            raise ValueError(
-                "checkpointing round-trips the carry through host npz — "
-                "not supported under a mesh (GSPMD carry placements); "
-                "checkpoint unsharded runs"
-            )
-        if auto_chunk:
-            raise ValueError(
-                "chunk_size='auto' picks chunk boundaries from wall-clock "
-                "timings — pass a fixed chunk_size when checkpointing so "
-                "the save points are deterministic"
-            )
-        if not scan and store != "offload":
-            raise ValueError(
-                "checkpointing rides the chunked scan driver (or the "
-                "host-driven offload loop) — drop scan=False"
-            )
-    byte_clock = (clock is not None
-                  and getattr(clock, "bandwidth_bps", None) is not None)
-    if byte_clock:
-        # logical model size BEFORE the lane-padding ravel: the wire
-        # never carries padding (core/compress.py)
-        model_size = pt.tree_size(state["x"])
-        clock = clock.with_wire(
-            compress.uplink_bytes(wire_comp, model_size),
-            compress.downlink_bytes(model_size),
-        )
-    if overlap == "scatter" and clock is not None:
-        # overlapped rounds pay max(compute, comm) instead of their sum
-        clock = clock.with_overlap()
-    fp = None
-    if ckpt_on:
-        fp = _config_fingerprint(
-            algo=getattr(algo, "name", type(algo).__name__),
-            num_clients=algo.fed.num_clients,
-            tol=tol, tol_metric=tol_metric, flat=bool(flat), store=store,
-            aggregate=aggregate, overlap=overlap,
-            async_rounds=bool(async_rounds), max_staleness=max_staleness,
-            stale_weighting=stale_weighting, stale_decay=stale_decay,
-            participation=participation, clock=clock, compression=wire_comp,
-            error_feedback=bool(error_feedback), topk_frac=topk_frac,
-            faults=faults, screening=screening, quorum=quorum,
-            watchdog=bool(watchdog), watchdog_patience=watchdog_patience,
-            watchdog_factor=watchdog_factor)
-    spec = pt.ravel_spec(state["x"]) if flat else None
-    if flat:
-        # the ONE ravel of the run: everything downstream carries the
-        # contiguous buffers; the inverse runs at the return boundary.
-        state = flatten_state(algo, state, spec)
-        if compressor is not None and compressor.error_feedback \
-                and "ef" not in state:
-            state["ef"] = jnp.zeros(
-                (algo.fed.num_clients, spec.padded_size), spec.dtype)
-        if faults is not None and faults.needs_prev \
-                and "fault_prev" not in state:
-            # the replay fault's stale-upload buffer: engine-created like
-            # "ef" above, rides `flat_client_keys` so it shards, offloads
-            # and unflattens like any other per-client flat buffer
-            state["fault_prev"] = jnp.zeros(
-                (algo.fed.num_clients, spec.padded_size), spec.dtype)
-        if overlap == "scatter":
-            # seed the double-buffered carry slot: row 0 = the initial
-            # anchor (== mean(z⁰) for FedGiA, == the barrier's round-0
-            # anchor for the baselines), extra rows (algorithm riders,
-            # e.g. SCAFFOLD's control-variate delta) = exact zeros.
-            rows = int(getattr(algo, "overlap_slot_rows", 1))
-            slot0 = state["x"][None]
-            if rows > 1:
-                slot0 = jnp.concatenate([
-                    slot0,
-                    jnp.zeros((rows - 1, spec.padded_size), slot0.dtype),
-                ])
-            state["ovl_shard"] = slot0
-    if store != "offload":
-        round_fn = make_round_fn(algo, mesh, client_axis, masked=masked,
-                                 stale=async_rounds, flat_spec=spec,
-                                 active_capacity=active_capacity,
-                                 compressor=compressor, overlap=overlap,
-                                 donate_kernel=donate_kernel,
-                                 aggregate=aggregate,
-                                 faults=faults, screening=screening)
-    if mesh is not None:
-        state, batch = shard_inputs(algo, state, batch, mesh, client_axis)
-    if donate is None:
-        # CPU XLA cannot alias buffers; donating would only emit warnings
-        donate = jax.default_backend() != "cpu"
-    stale0 = (
-        api.init_stale_xbar(state["x"], algo.fed.num_clients, max_staleness,
-                            weighting=stale_weighting, decay=stale_decay)
-        if async_rounds else ()
-    )
-    guard = _make_guard(quorum, watchdog, watchdog_patience, watchdog_factor)
-    ws0 = ()
-    if watchdog:
-        # the snapshot slot starts as a COPY of the initial state: a
-        # shared buffer would alias the donated carry's state leaves
-        ws0 = {"best": jnp.full((), jnp.inf, jnp.float32),
-               "bad": jnp.zeros((), jnp.int32),
-               "snap": jax.tree.map(jnp.copy, state)}
-    if store == "offload":
-        res = _run_offload_loop(
-            algo, state, batch, num_rounds, tol, tol_metric,
-            participation, clock, stale0, async_rounds, spec,
-            active_capacity, compressor, donate_kernel,
-            packed=(aggregate == "packed"), max_staleness=max_staleness,
-            faults=faults, screening=screening,
-            quorum=quorum, checkpoint_every=checkpoint_every,
-            checkpoint_dir=checkpoint_dir, resume=resume, fingerprint=fp)
-        return dataclasses.replace(
-            res, state=unflatten_state(algo, res.state, spec))
-    if not scan:
-        res = _run_legacy_loop(round_fn, state, batch, num_rounds, tol,
-                               tol_metric, participation, stale0,
-                               async_rounds, clock, guard=guard, ws0=ws0,
-                               donate=donate and mesh is None)
-        if flat:
-            st = res.state
-            if overlap == "scatter":
-                st = _finalize_overlap(algo, st)
-            res = dataclasses.replace(
-                res, state=unflatten_state(algo, st, spec))
-        return res
-    if auto_chunk:
-        chunk_size = AUTO_CHUNK_CANDIDATES[0]
-    elif chunk_size <= 0:
-        chunk_size = num_rounds if tol <= 0 else min(num_rounds, 32)
-
-    pstate = participation.init() if participation is not None else ()
-    cstate = clock.init() if clock is not None else ()
-
-    def call_round(st, b, ps, cs, sl, n):
-        """One round + advanced policy/clock/staleness state (from the carry)."""
-        if clock is not None:
-            mask, now, cs2 = clock.tick(cs, n)
-            s2, sl2, met = round_fn(st, b, mask, sl)
-            met = _with_staleness_metrics(met, sl2)
-            met["sim_time"] = now
-            if byte_clock:
-                met = _with_byte_metrics(met, mask, clock)
-            return s2, ps, cs2, sl2, met
-        if not masked:
-            s2, met = round_fn(st, b)
-            return s2, ps, cs, sl, met
-        mask, ps2 = participation.mask(ps, n)
-        if async_rounds:
-            s2, sl2, met = round_fn(st, b, mask, sl)
-            return s2, ps2, cs, sl2, _with_staleness_metrics(met, sl2)
-        s2, met = round_fn(st, b, mask)
-        return s2, ps2, cs, sl, met
-
-    def guarded_round(st, b, ps, cs, sl, ws, n):
-        """One round + the quorum/watchdog guard (identity — and
-        structurally absent — when both are off)."""
-        s2, ps2, cs2, sl2, met = call_round(st, b, ps, cs, sl, n)
-        if guard is not None:
-            s2, sl2, ws, met = guard(st, sl, s2, sl2, ws, met)
-        return s2, ps2, cs2, sl2, ws, met
-
-    _, _, _, _, _, abs_met = jax.eval_shape(
-        guarded_round, state, batch, pstate, cstate, stale0, ws0,
-        jnp.zeros((), jnp.int32)
-    )
-
-    def chunk_fn(carry, batch, *, length):
-        def step(carry, _):
-            st, ps, cs, sl, ws, done, n = carry
-            if tol > 0:
-                def live(op):
-                    st_, ps_, cs_, sl_, ws_, b_, n_ = op
-                    s2, ps2, cs2, sl2, ws2, met = guarded_round(
-                        st_, b_, ps_, cs_, sl_, ws_, n_)
-                    return (s2, ps2, cs2, sl2, ws2, met,
-                            met[tol_metric] < tol, n_ + 1)
-
-                def frozen(op):
-                    st_, ps_, cs_, sl_, ws_, _, n_ = op
-                    zeros = jax.tree.map(
-                        lambda l: jnp.zeros(l.shape, l.dtype), abs_met
-                    )
-                    return (st_, ps_, cs_, sl_, ws_, zeros,
-                            jnp.ones((), bool), n_)
-
-                s2, ps2, cs2, sl2, ws2, met, d2, n2 = jax.lax.cond(
-                    done, frozen, live, (st, ps, cs, sl, ws, batch, n)
+                f"fault model covers {faults.num_clients} clients, algorithm "
+                f"has {algo.fed.num_clients}")
+        if quorum:
+            if not 0 < quorum <= algo.fed.num_clients:
+                raise ValueError(
+                    f"quorum must be in [0, m={algo.fed.num_clients}], "
+                    f"got {quorum}")
+            if not masked and faults is None and screening is None:
+                raise ValueError(
+                    "quorum needs a source of non-arrival to guard against "
+                    "— pass participation=, clock=, faults= or screening="
                 )
-            else:
-                s2, ps2, cs2, sl2, ws2, met = guarded_round(
-                    st, batch, ps, cs, sl, ws, n)
-                d2, n2 = done, n + 1
-            return (s2, ps2, cs2, sl2, ws2, d2, n2), met
-
-        return jax.lax.scan(step, carry, None, length=length)
-
-    donate_args = (0,) if donate else ()
-    if donate:
-        # donation must never consume the CALLER's buffers (states are
-        # routinely reused across run_rounds calls, e.g. scan-vs-loop
-        # comparisons); copy once up front so every donated carry after
-        # that is engine-owned.
-        state = jax.tree.map(jnp.copy, state)
-    chunks: Dict[int, Any] = {}
-
-    def get_chunk(length: int):
-        if length not in chunks:
-            chunks[length] = jax.jit(
-                functools.partial(chunk_fn, length=length),
-                donate_argnums=donate_args,
+        deadline_clock = (clock is not None
+                          and getattr(clock, "deadline_s", None) is not None)
+        if deadline_clock and quorum < 1:
+            raise ValueError(
+                "a deadline clock (ComputeClock(deadline_s=)) can cut rounds "
+                "with ZERO arrivals — pass quorum >= 1 so they degrade to "
+                "recorded no-ops instead of a 0-client mean"
             )
-        return chunks[length]
-
-    carry = (state, pstate, cstate, stale0, ws0, jnp.zeros((), bool),
-             jnp.zeros((), jnp.int32))
-
-    start_round = 0
-    saved_hist = None
-    if resume:
-        step0 = ckpt_io.latest_step(checkpoint_dir)
-        if step0 is not None:
-            # fingerprint FIRST (json only): a mismatched config often
-            # also means a mismatched carry structure, and the clean
-            # error must win over an npz leaf-count assertion
-            _check_fingerprint(checkpoint_dir, step0, fp)
-            # history dtypes come from abs_met (shapes from the file);
-            # the fingerprint guarantees the key set matches
-            hist_like = {k: np.zeros((0,), l.dtype)
-                         for k, l in abs_met.items()}
-            (carry, saved_hist), _ = ckpt_io.load_checkpoint(
-                checkpoint_dir, step0, (carry, hist_like))
-            start_round = step0
-
-    # chunk_size="auto": the first chunks run the candidate lengths in
-    # turn (clipped to the rounds left — the rounds executed are the same
-    # whatever the timings), then the fastest per-round candidate drives
-    # the remainder.
-    plan = None
-    if auto_chunk:
-        plan, rem_after = [], num_rounds
-        for cand in AUTO_CHUNK_CANDIDATES:
-            if rem_after <= 0:
-                break
-            plan.append(min(cand, rem_after))
-            rem_after -= plan[-1]
-
-    if mesh is None and not ckpt_on:
-        # Pre-compile (AOT) every chunk length this run can need — at most
-        # two (fixed chunk) or the candidate set plus each possible
-        # remainder (auto) — so wall_s measures execution, matching the
-        # legacy warm-up convention. The compiled executables are called
-        # directly; on a single device input/output placements are
-        # trivially consistent. (Under a mesh, GSPMD may re-place carry
-        # leaves between chunks, so there we let jit handle compilation on
-        # first call instead. With checkpointing on, chunk lengths are
-        # additionally capped at checkpoint boundaries — those compile
-        # lazily via get_chunk, so wall_s may include compile time.)
+        if watchdog:
+            if watchdog_patience < 1:
+                raise ValueError(
+                    f"watchdog_patience must be >= 1, got {watchdog_patience}")
+            if watchdog_factor <= 1.0:
+                raise ValueError(
+                    "watchdog_factor must be > 1 (a divergence threshold "
+                    f"RELATIVE to the best f̄ seen), got {watchdog_factor}")
+            if store == "offload":
+                raise ValueError(
+                    "the watchdog keeps a full state snapshot in the carry "
+                    "— under store='offload' that would double the "
+                    "host-resident buffers; run the watchdog with "
+                    "store='dense'/'active'"
+                )
+        if checkpoint_every < 0:
+            raise ValueError(
+                f"checkpoint_every must be >= 0, got {checkpoint_every}")
+        ckpt_on = checkpoint_every > 0 or resume
+        if ckpt_on:
+            if checkpoint_dir is None:
+                raise ValueError(
+                    "checkpoint_every/resume need a checkpoint_dir= to write "
+                    "to / restore from")
+            if mesh is not None:
+                raise ValueError(
+                    "checkpointing round-trips the carry through host npz — "
+                    "not supported under a mesh (GSPMD carry placements); "
+                    "checkpoint unsharded runs"
+                )
+            if auto_chunk:
+                raise ValueError(
+                    "chunk_size='auto' picks chunk boundaries from "
+                    "wall-clock timings — pass a fixed chunk_size when "
+                    "checkpointing so the save points are deterministic"
+                )
+            if not scan and store != "offload":
+                raise ValueError(
+                    "checkpointing rides the chunked scan driver (or the "
+                    "host-driven offload loop) — drop scan=False"
+                )
+        byte_clock = (clock is not None
+                      and getattr(clock, "bandwidth_bps", None) is not None)
+        if byte_clock:
+            # logical model size BEFORE the lane-padding ravel: the wire
+            # never carries padding (core/compress.py)
+            model_size = pt.tree_size(state["x"])
+            clock = clock.with_wire(
+                compress.uplink_bytes(wire_comp, model_size),
+                compress.downlink_bytes(model_size),
+            )
+        if overlap == "scatter" and clock is not None:
+            # overlapped rounds pay max(compute, comm) instead of their sum
+            clock = clock.with_overlap()
+        fp = None
+        if ckpt_on:
+            fp = _config_fingerprint(
+                algo=getattr(algo, "name", type(algo).__name__),
+                num_clients=algo.fed.num_clients,
+                tol=tol, tol_metric=tol_metric, flat=bool(flat), store=store,
+                aggregate=aggregate, overlap=overlap,
+                async_rounds=bool(async_rounds), max_staleness=max_staleness,
+                stale_weighting=stale_weighting, stale_decay=stale_decay,
+                participation=participation, clock=clock,
+                compression=wire_comp,
+                error_feedback=bool(error_feedback), topk_frac=topk_frac,
+                faults=faults, screening=screening, quorum=quorum,
+                watchdog=bool(watchdog), watchdog_patience=watchdog_patience,
+                watchdog_factor=watchdog_factor)
+        spec = pt.ravel_spec(state["x"]) if flat else None
+        if flat:
+            # the ONE ravel of the run: everything downstream carries the
+            # contiguous buffers; the inverse runs at the return boundary.
+            state = flatten_state(algo, state, spec)
+            if compressor is not None and compressor.error_feedback \
+                    and "ef" not in state:
+                state["ef"] = jnp.zeros(
+                    (algo.fed.num_clients, spec.padded_size), spec.dtype)
+            if faults is not None and faults.needs_prev \
+                    and "fault_prev" not in state:
+                # the replay fault's stale-upload buffer: engine-created like
+                # "ef" above, rides `flat_client_keys` so it shards, offloads
+                # and unflattens like any other per-client flat buffer
+                state["fault_prev"] = jnp.zeros(
+                    (algo.fed.num_clients, spec.padded_size), spec.dtype)
+            if overlap == "scatter":
+                # seed the double-buffered carry slot: row 0 = the initial
+                # anchor (== mean(z⁰) for FedGiA, == the barrier's round-0
+                # anchor for the baselines), extra rows (algorithm riders,
+                # e.g. SCAFFOLD's control-variate delta) = exact zeros.
+                rows = int(getattr(algo, "overlap_slot_rows", 1))
+                slot0 = state["x"][None]
+                if rows > 1:
+                    slot0 = jnp.concatenate([
+                        slot0,
+                        jnp.zeros((rows - 1, spec.padded_size), slot0.dtype),
+                    ])
+                state["ovl_shard"] = slot0
+        if store != "offload":
+            round_fn = make_round_fn(algo, mesh, client_axis, masked=masked,
+                                     stale=async_rounds, flat_spec=spec,
+                                     active_capacity=active_capacity,
+                                     compressor=compressor, overlap=overlap,
+                                     donate_kernel=donate_kernel,
+                                     aggregate=aggregate,
+                                     faults=faults, screening=screening)
+        if mesh is not None:
+            state, batch = shard_inputs(algo, state, batch, mesh, client_axis)
+        if donate is None:
+            # CPU XLA cannot alias buffers; donating would only emit warnings
+            donate = jax.default_backend() != "cpu"
+        stale0 = (
+            api.init_stale_xbar(state["x"], algo.fed.num_clients,
+                                max_staleness, weighting=stale_weighting,
+                                decay=stale_decay)
+            if async_rounds else ()
+        )
+        guard = _make_guard(quorum, watchdog, watchdog_patience,
+                            watchdog_factor)
+        ws0 = ()
+        if watchdog:
+            # the snapshot slot starts as a COPY of the initial state: a
+            # shared buffer would alias the donated carry's state leaves
+            ws0 = {"best": jnp.full((), jnp.inf, jnp.float32),
+                   "bad": jnp.zeros((), jnp.int32),
+                   "snap": jax.tree.map(jnp.copy, state)}
+        # the host-driven loops below are not preparation
+        if store == "offload":
+            prepare.close()
+            res = _run_offload_loop(
+                algo, state, batch, num_rounds, tol, tol_metric,
+                participation, clock, stale0, async_rounds, spec,
+                active_capacity, compressor, donate_kernel,
+                packed=(aggregate == "packed"), max_staleness=max_staleness,
+                faults=faults, screening=screening,
+                quorum=quorum, checkpoint_every=checkpoint_every,
+                checkpoint_dir=checkpoint_dir, resume=resume, fingerprint=fp)
+            return dataclasses.replace(
+                res, state=unflatten_state(algo, res.state, spec))
+        if not scan:
+            prepare.close()
+            res = _run_legacy_loop(round_fn, state, batch, num_rounds, tol,
+                                   tol_metric, participation, stale0,
+                                   async_rounds, clock, guard=guard, ws0=ws0,
+                                   donate=donate and mesh is None)
+            if flat:
+                st = res.state
+                if overlap == "scatter":
+                    st = _finalize_overlap(algo, st)
+                res = dataclasses.replace(
+                    res, state=unflatten_state(algo, st, spec))
+            return res
         if auto_chunk:
-            lengths = set(plan)
-            if tol <= 0 and rem_after > 0:
-                # whatever candidate wins, the remainder runs full chunks
-                # of it plus one partial chunk
-                for cand in set(plan):
-                    lengths.add(min(cand, rem_after))
-                    if rem_after % cand:
-                        lengths.add(rem_after % cand)
-        else:
-            lengths = {min(chunk_size, num_rounds)}
-            if num_rounds % chunk_size and tol <= 0:
-                # with tol off the remainder chunk always runs; with tol
-                # on, converging runs never reach it, so leave it to
-                # compile lazily (get_chunk falls back to plain jit on
-                # first call)
-                lengths.add(num_rounds % chunk_size)
-        abs_of = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
-        for length in lengths:
-            chunks[length] = get_chunk(length).lower(
-                jax.tree.map(abs_of, carry), jax.tree.map(abs_of, batch)
-            ).compile()
+            chunk_size = AUTO_CHUNK_CANDIDATES[0]
+        elif chunk_size <= 0:
+            chunk_size = num_rounds if tol <= 0 else min(num_rounds, 32)
+
+        pstate = participation.init() if participation is not None else ()
+        cstate = clock.init() if clock is not None else ()
+
+        def call_round(st, b, ps, cs, sl, n):
+            """One round + advanced policy/clock/staleness state (from the
+            carry)."""
+            if clock is not None:
+                mask, now, cs2 = clock.tick(cs, n)
+                s2, sl2, met = round_fn(st, b, mask, sl)
+                met = _with_staleness_metrics(met, sl2)
+                met["sim_time"] = now
+                if byte_clock:
+                    met = _with_byte_metrics(met, mask, clock)
+                return s2, ps, cs2, sl2, met
+            if not masked:
+                s2, met = round_fn(st, b)
+                return s2, ps, cs, sl, met
+            mask, ps2 = participation.mask(ps, n)
+            if async_rounds:
+                s2, sl2, met = round_fn(st, b, mask, sl)
+                return s2, ps2, cs, sl2, _with_staleness_metrics(met, sl2)
+            s2, met = round_fn(st, b, mask)
+            return s2, ps2, cs, sl, met
+
+        def guarded_round(st, b, ps, cs, sl, ws, n):
+            """One round + the quorum/watchdog guard (identity — and
+            structurally absent — when both are off)."""
+            s2, ps2, cs2, sl2, met = call_round(st, b, ps, cs, sl, n)
+            if guard is not None:
+                s2, sl2, ws, met = guard(st, sl, s2, sl2, ws, met)
+            return s2, ps2, cs2, sl2, ws, met
+
+        _, _, _, _, _, abs_met = jax.eval_shape(
+            guarded_round, state, batch, pstate, cstate, stale0, ws0,
+            jnp.zeros((), jnp.int32)
+        )
+
+        def chunk_fn(carry, batch, *, length):
+            def step(carry, _):
+                st, ps, cs, sl, ws, done, n = carry
+                if tol > 0:
+                    def live(op):
+                        st_, ps_, cs_, sl_, ws_, b_, n_ = op
+                        s2, ps2, cs2, sl2, ws2, met = guarded_round(
+                            st_, b_, ps_, cs_, sl_, ws_, n_)
+                        return (s2, ps2, cs2, sl2, ws2, met,
+                                met[tol_metric] < tol, n_ + 1)
+
+                    def frozen(op):
+                        st_, ps_, cs_, sl_, ws_, _, n_ = op
+                        zeros = jax.tree.map(
+                            lambda l: jnp.zeros(l.shape, l.dtype), abs_met
+                        )
+                        return (st_, ps_, cs_, sl_, ws_, zeros,
+                                jnp.ones((), bool), n_)
+
+                    s2, ps2, cs2, sl2, ws2, met, d2, n2 = jax.lax.cond(
+                        done, frozen, live, (st, ps, cs, sl, ws, batch, n)
+                    )
+                else:
+                    s2, ps2, cs2, sl2, ws2, met = guarded_round(
+                        st, batch, ps, cs, sl, ws, n)
+                    d2, n2 = done, n + 1
+                return (s2, ps2, cs2, sl2, ws2, d2, n2), met
+
+            return jax.lax.scan(step, carry, None, length=length)
+
+        donate_args = (0,) if donate else ()
+        if donate:
+            # donation must never consume the CALLER's buffers (states are
+            # routinely reused across run_rounds calls, e.g. scan-vs-loop
+            # comparisons); copy once up front so every donated carry after
+            # that is engine-owned.
+            state = jax.tree.map(jnp.copy, state)
+        chunks: Dict[int, Any] = {}
+
+        def get_chunk(length: int):
+            if length not in chunks:
+                chunks[length] = jax.jit(
+                    functools.partial(chunk_fn, length=length),
+                    donate_argnums=donate_args,
+                )
+            return chunks[length]
+
+        carry = (state, pstate, cstate, stale0, ws0, jnp.zeros((), bool),
+                 jnp.zeros((), jnp.int32))
+
+        start_round = 0
+        saved_hist = None
+        if resume:
+            step0 = ckpt_io.latest_step(checkpoint_dir)
+            if step0 is not None:
+                # fingerprint FIRST (json only): a mismatched config often
+                # also means a mismatched carry structure, and the clean
+                # error must win over an npz leaf-count assertion
+                _check_fingerprint(checkpoint_dir, step0, fp)
+                # history dtypes come from abs_met (shapes from the file);
+                # the fingerprint guarantees the key set matches
+                hist_like = {k: np.zeros((0,), l.dtype)
+                             for k, l in abs_met.items()}
+                (carry, saved_hist), _ = ckpt_io.load_checkpoint(
+                    checkpoint_dir, step0, (carry, hist_like))
+                start_round = step0
+
+        # chunk_size="auto": the first chunks run the candidate lengths in
+        # turn (clipped to the rounds left — the rounds executed are the same
+        # whatever the timings), then the fastest per-round candidate drives
+        # the remainder.
+        plan = None
+        if auto_chunk:
+            plan, rem_after = [], num_rounds
+            for cand in AUTO_CHUNK_CANDIDATES:
+                if rem_after <= 0:
+                    break
+                plan.append(min(cand, rem_after))
+                rem_after -= plan[-1]
+
+        lengths = ()  # compiled lazily by get_chunk
+        if mesh is None and not ckpt_on:
+            # Pre-compile (AOT) every chunk length this run can need — at
+            # most two (fixed chunk) or the candidate set plus each possible
+            # remainder (auto) — so wall_s measures execution, matching the
+            # legacy warm-up convention. The compiled executables are called
+            # directly; on a single device input/output placements are
+            # trivially consistent. (Under a mesh, GSPMD may re-place carry
+            # leaves between chunks, so there we let jit handle compilation on
+            # first call instead. With checkpointing on, chunk lengths are
+            # additionally capped at checkpoint boundaries — those compile
+            # lazily via get_chunk, so wall_s may include compile time.)
+            if auto_chunk:
+                lengths = set(plan)
+                if tol <= 0 and rem_after > 0:
+                    # whatever candidate wins, the remainder runs full chunks
+                    # of it plus one partial chunk
+                    for cand in set(plan):
+                        lengths.add(min(cand, rem_after))
+                        if rem_after % cand:
+                            lengths.add(rem_after % cand)
+            else:
+                lengths = {min(chunk_size, num_rounds)}
+                if num_rounds % chunk_size and tol <= 0:
+                    # with tol off the remainder chunk always runs; with tol
+                    # on, converging runs never reach it, so leave it to
+                    # compile lazily (get_chunk falls back to plain jit on
+                    # first call)
+                    lengths.add(num_rounds % chunk_size)
+
+    abs_of = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    for length in lengths:
+        with jax.profiler.TraceAnnotation("run_rounds.lower"):
+            lowered = get_chunk(length).lower(
+                jax.tree.map(abs_of, carry), jax.tree.map(abs_of, batch))
+        with jax.profiler.TraceAnnotation("run_rounds.compile"):
+            chunks[length] = lowered.compile()
 
     chunk_metrics = [] if saved_hist is None else [saved_hist]
     timings = []
@@ -1102,14 +1132,14 @@ def run_rounds(
     next_ckpt = None
     if checkpoint_every > 0:
         next_ckpt = (executed // checkpoint_every + 1) * checkpoint_every
-    t0 = time.time()
+    t0 = time.perf_counter()
     while remaining > 0:
         if plan:
             c = plan.pop(0)
-            tc = time.time()
+            tc = time.perf_counter()
             carry, mets = get_chunk(c)(carry, batch)
             jax.block_until_ready(carry[6])
-            timings.append(((time.time() - tc) / c, c))
+            timings.append(((time.perf_counter() - tc) / c, c))
             if not plan:
                 chunk_size = min(timings)[1]
         else:
@@ -1131,19 +1161,21 @@ def run_rounds(
             break
     state, _, _, _, _, done, n = carry
     jax.block_until_ready(n)
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0
 
-    rounds_run = int(n)
-    stopped = tol > 0 and bool(jax.device_get(done))
-    mets_host = jax.device_get(chunk_metrics)
-    history = {
-        k: np.concatenate([np.asarray(m[k]) for m in mets_host])[:rounds_run]
-        for k in mets_host[0]
-    }
-    if flat:
-        if overlap == "scatter":
-            state = _finalize_overlap(algo, state)
-        state = unflatten_state(algo, state, spec)
+    with jax.profiler.TraceAnnotation("run_rounds.fetch"):
+        rounds_run = int(n)
+        stopped = tol > 0 and bool(jax.device_get(done))
+        mets_host = jax.device_get(chunk_metrics)
+        history = {
+            k: np.concatenate(
+                [np.asarray(m[k]) for m in mets_host])[:rounds_run]
+            for k in mets_host[0]
+        }
+        if flat:
+            if overlap == "scatter":
+                state = _finalize_overlap(algo, state)
+            state = unflatten_state(algo, state, spec)
     return RoundResult(state, history, rounds_run, stopped, wall)
 
 
@@ -1383,7 +1415,7 @@ def _run_legacy_loop(round_fn, state, batch, num_rounds, tol, tol_metric,
         jax.block_until_ready(_m)
     hist = []
     stopped = False
-    t0 = time.time()
+    t0 = time.perf_counter()
     for i in range(num_rounds):
         state, pstate, cstate, sstate, wstate, met = rfn(
             state, pstate, cstate, sstate, wstate, batch, jnp.int32(i))
@@ -1392,7 +1424,7 @@ def _run_legacy_loop(round_fn, state, batch, num_rounds, tol, tol_metric,
         if tol > 0 and float(met_h[tol_metric]) < tol:
             stopped = True
             break
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0
     history = {k: np.asarray([h[k] for h in hist]) for k in hist[0]} if hist else {}
     return RoundResult(state, history, len(hist), stopped, wall)
 
@@ -1619,7 +1651,7 @@ def _run_offload_loop(algo, state, batch, num_rounds, tol, tol_metric,
         extras["tile_memory_kind"] = pt.memory_kind(
             jax.tree.leaves(tile_h)[0])
         staged = to_dev(tile_h)
-    t0 = time.time()
+    t0 = time.perf_counter()
     for i in range(start_round, num_rounds):
         if population:
             tiles = to_dev(store.buffers)
@@ -1693,7 +1725,7 @@ def _run_offload_loop(algo, state, batch, num_rounds, tol, tol_metric,
                 checkpoint_dir, i + 1,
                 (jax.device_get(ckpt_tree(pcs_prev)), hist_np),
                 extra={"fingerprint": fingerprint})
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0
     # where the resident buffers ARE after the last round's scatter
     resident = (list(store.buffers.values()) + jax.tree.leaves(batch_h)
                 + ([anchor_h] if async_rounds else []))
