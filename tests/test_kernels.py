@@ -10,7 +10,12 @@ from repro.kernels.fedgia_update import (
     fedgia_update_flat,
     fedgia_update_ref,
 )
-from repro.kernels.fedgia_update.kernel import LANES, SEL_BLOCK
+from repro.kernels.fedgia_update.kernel import (
+    LANES,
+    MAX_BLOCK_LANES,
+    batched_blocks,
+    fedgia_update_kernel,
+)
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
 from repro.kernels.rwkv6_scan import rwkv6_scan, rwkv6_scan_ref
 
@@ -94,23 +99,75 @@ def test_fedgia_update_batched_matches_ref(n, k0):
                                    rtol=2e-5, atol=2e-5, err_msg=name)
 
 
-def test_fedgia_update_batched_sel_spans_smem_blocks():
-    """The branch select enters SMEM SEL_BLOCK clients at a time: a client
-    count that spans two full blocks and a ragged third must still read
-    each client's own select bit."""
-    m, n = 2 * SEL_BLOCK + 5, LANES
+# client blocks at N = 128 lanes, and at the widest column block
+BM_128 = batched_blocks(10**6, LANES)[0]
+BM_WIDE = batched_blocks(10**6, MAX_BLOCK_LANES)[0]
+
+
+def _every_third(m):
+    return np.arange(m) % 3 == 0
+
+
+def _random_half(m):
+    return RNG.random(m) < 0.5
+
+
+@pytest.mark.parametrize("m,n,pick", [
+    (2 * BM_128 + 5, LANES, _random_half),
+    (BM_128 + 130, LANES, _every_third),
+    (1003, LANES, _every_third),
+    (10, MAX_BLOCK_LANES + 3 * LANES, _random_half),
+    (2 * BM_WIDE + 5, MAX_BLOCK_LANES + 3 * LANES, _every_third),
+], ids=["ragged_clients", "sel_inside_subblock", "unaligned_clients",
+        "ragged_columns", "ragged_both"])
+def test_fedgia_update_batched_tiles(m, n, pick):
+    """The (BM, BN) tiling: ragged last client and column blocks, and a
+    select that changes inside one 128-client run, read each client's
+    own select bit. Matches the jnp oracle, and each row equals the
+    single-vector kernel on that client bit for bit."""
     xbar, g, pi = (jnp.asarray(RNG.standard_normal((m, n)), jnp.float32)
                    for _ in range(3))
     h = jnp.asarray(RNG.uniform(0.05, 3.0, (m, n)), jnp.float32)
-    sel = jnp.asarray(RNG.random(m) < 0.5)
+    sel = jnp.asarray(pick(m))
     sigma = jnp.float32(0.7)
     ref = fedgia_update_flat(xbar, g, pi, h, sel, sigma, m, k0=3,
                              use_kernel=False)
     out = fedgia_update_flat(xbar, g, pi, h, sel, sigma, m, k0=3,
                              use_kernel=True, interpret=True)
-    for a, b, name in zip(out, ref, ("x", "pi", "z")):
+    single = jax.vmap(lambda *v: fedgia_update_kernel(
+        *v, sigma, m, k0=3, interpret=True))(xbar, g, pi, h, sel)
+    for a, b, c, name in zip(out, ref, single, ("x", "pi", "z")):
+        assert a.shape == (m, n), name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-5, err_msg=name)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("mb,n", [
+    (10**6, 128), (10**6 + 3, 128), (128, 128), (128, 1024), (6, 256),
+    (1003, 128), (200, 384), (8, 2**20), (2**14, 2**16), (133, 2432),
+    (6, 128), (64, 128), (40, 384),
+])
+def test_fedgia_update_batched_block_rule(mb, n):
+    """`batched_blocks` tiles lane-aligned columns, takes BM a multiple
+    of the sublane tile (or all mb clients) and within one select tile,
+    keeps the seven double-buffered streams under 16 MiB of VMEM, and
+    gives the pipeline two steps or more where the state can be cut."""
+    bm, bn = batched_blocks(mb, n)
+    assert bn % LANES == 0 and bn <= min(n, MAX_BLOCK_LANES)
+    assert bm == mb or (bm % 32 == 0 and bm < mb)
+    assert bm <= 8 * LANES
+    assert 7 * 2 * bm * bn * 4 + 2 * 8 * LANES * 4 < 16 * 2**20
+    steps = -(-mb // bm) * -(-n // bn)
+    assert steps >= 2 or (mb < 64 and n == LANES)
+    if (mb, n) == (10**6, 128):
+        assert steps <= 2000
+
+
+def test_fedgia_update_batched_block_rule_needs_lanes():
+    with pytest.raises(ValueError, match="multiple of 128"):
+        batched_blocks(8, LANES + 1)
 
 
 def _donation_args(m=6, n=2 * LANES):
@@ -199,7 +256,7 @@ def test_fedgia_update_flat_donate_falls_back_when_padded():
 
 def test_fedgia_update_batched_rowwise_equals_single():
     """Each row of the batched kernel equals the single-vector kernel on
-    that client's slice (same interpret-mode lowering, same math)."""
+    that client's slice bit for bit (same per-element math, same order)."""
     m, n = 4, 2 * LANES
     xbar = jnp.asarray(RNG.standard_normal((m, n)), jnp.float32)
     g = jnp.asarray(RNG.standard_normal((m, n)), jnp.float32)
@@ -213,9 +270,8 @@ def test_fedgia_update_batched_rowwise_equals_single():
         single = fedgia_update(xbar[i], g[i], pi[i], h[i], bool(sel[i]),
                                sigma, m, k0=3, interpret=True)
         for a, b, name in zip(batched, single, ("x", "pi", "z")):
-            np.testing.assert_allclose(np.asarray(a[i]), np.asarray(b),
-                                       rtol=1e-6, atol=1e-6,
-                                       err_msg=f"client {i} {name}")
+            np.testing.assert_array_equal(np.asarray(a[i]), np.asarray(b),
+                                          err_msg=f"client {i} {name}")
 
 
 # ------------------------------------------------------------ flash_attention

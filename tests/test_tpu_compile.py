@@ -61,11 +61,16 @@ def mesh4(topo):
 
 
 @pytest.mark.parametrize("donate", [False, True], ids=["plain", "donated"])
-@pytest.mark.parametrize("shape", [(128, 128), (128, 1024), (10**6, 128)],
-                         ids=["128x128", "128x1024", "1e6x128"])
+@pytest.mark.parametrize("shape", [(128, 128), (128, 1024), (10**6, 128),
+                                   (10**6 + 3, 128), (8, 2**20)],
+                         ids=["128x128", "128x1024", "1e6x128", "1e6+3x128",
+                              "8x2^20"])
 def test_batched_kernel_compiles(one_chip, shape, donate):
-    """(10^6, 128) was refused while the whole (m,) branch select sat in
-    the 1 MiB SMEM; it now enters one block of clients at a time."""
+    """The blocks `batched_blocks` picks compile: 1024 clients a step at
+    (10^6, 128), a ragged last client block at 10^6 + 3, and 2048-lane
+    column blocks of a wide cross-silo state. Each client block's
+    selects enter VMEM as one (8, 128) int32 tile, which the kernel
+    transposes to put clients on sublanes."""
     sds = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt,
                                                           sharding=one_chip)
     args = (sds(shape),) * 4 + (sds(shape[:1], jnp.bool_), sds(()))
