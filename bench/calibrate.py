@@ -34,10 +34,12 @@ def readings(spec, cell, seed: int, control: bool) -> dict:
     cfg, data, problem, caller, res = run.first_call(spec, cell, seed)
     t1 = time.time()
     prog = workload.host_outputs(cfg, res)
+    mesh = problem.mesh
     workload.free(res.state, problem.state0, problem.batch)
     del res, problem, caller
     gc.collect()
-    ref = workload.reference_outputs(cfg, data, seed, prog["rounds_run"])
+    ref = workload.reference_outputs(cfg, data, seed, prog["rounds_run"],
+                                     mesh=mesh)
     t2 = time.time()
     out = {"seed": seed, "cell": cell["name"],
            "rounds": prog["rounds_run"], "stopped": prog["stopped_early"],
@@ -45,7 +47,7 @@ def readings(spec, cell, seed: int, control: bool) -> dict:
            "program": compare.numbers(prog, ref, limits["grad_floor"])}
     if control:
         ctl = workload.reference_outputs(cfg, data, seed, prog["rounds_run"],
-                                         "high")
+                                         "high", mesh=mesh)
         out["control"] = compare.numbers(ctl, ref, limits["grad_floor"])
     return out
 

@@ -18,6 +18,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench.reference import by_rows
+
 NUMBERS = ("loss_gap", "grad_gap", "state_gap", "selected_gap")
 HISTORY = ("f_xbar", "grad_sq_norm", "selected")  # per round
 NON_FINITE = 1e300  # a gap that is not a number reads as this
@@ -27,11 +29,17 @@ def _finite(v: float) -> float:
     return float(v) if np.isfinite(v) else NON_FINITE
 
 
+def _row_norms(prog: np.ndarray, ref: np.ndarray) -> np.ndarray:
+    """(‖program − reference‖, ‖reference‖) of each row, in float64."""
+    prog = np.asarray(prog, np.float64)
+    ref = np.asarray(ref, np.float64)
+    return np.stack([np.linalg.norm(prog - ref, axis=1),
+                     np.linalg.norm(ref, axis=1)], axis=1)
+
+
 def row_gap(prog: np.ndarray, ref: np.ndarray) -> float:
-    prog = np.asarray(prog, np.float64).reshape(-1, np.shape(ref)[-1])
-    ref = np.asarray(ref, np.float64).reshape(prog.shape)
-    diff = np.linalg.norm(prog - ref, axis=1)
-    norm = np.linalg.norm(ref, axis=1)
+    prog = np.reshape(prog, (-1, np.shape(ref)[-1]))
+    diff, norm = by_rows(_row_norms, prog, np.reshape(ref, prog.shape)).T
     scale = np.maximum(norm, np.median(norm))
     scale = np.where(scale > 0, scale, 1.0)
     if not np.all(np.isfinite(diff)):

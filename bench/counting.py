@@ -23,8 +23,14 @@ read better.
 Elementwise work at the sample level (residuals, sigmoids) is not
 counted. The linear models multiply in float32 at `Precision.HIGHEST`;
 the peak they are held against is the chip's bf16 one.
+
+A problem whose gradient is not the linear one gives its own counts:
+`counts(cfg)` in `bench/losses/<problem>.py`, with the keys of
+`for_config`.
 """
 from __future__ import annotations
+
+from bench import reference
 
 def int_pow_mults(p: int) -> int:
     """Multiplies of x**p by repeated squaring (p >= 1)."""
@@ -48,7 +54,12 @@ def round_flops(m: int, n: int, d: int, k0: int) -> int:
 
 
 def for_config(cfg: dict) -> dict:
-    """{"flops_per_round", "kernel_bytes_per_round"} of a configuration."""
+    """{"flops_per_round", "kernel_bytes_per_round"} of a configuration:
+    the problem file's `counts(cfg)` where it gives them, else those of
+    FedGiA over a linear model."""
+    own = getattr(reference.load("losses", cfg["problem"]), "counts", None)
+    if own:
+        return own(cfg)
     m, n = cfg["num_clients"], cfg["dim"]
     d = cfg.get("samples", m)
     return {"flops_per_round": round_flops(m, n, d, cfg["k0"]),

@@ -1,8 +1,15 @@
 """What every plain reference shares: products at a stated precision,
 the per-client loss and gradient of a problem (`bench/losses/<problem>.py`),
-its Lipschitz bound, and the participants' draw. An algorithm's reference
-is `bench/references/<algorithm>.py`, found by the configuration's
-`algorithm`.
+its Lipschitz bound, the data laid out for it, and the participants'
+draw. An algorithm's reference is `bench/references/<algorithm>.py`,
+found by the configuration's `algorithm`.
+
+A problem file gives either the linear form, `terms` and `regulariser`
+of z = A x (and `lipschitz` from A), or, for a model whose parameters
+are a pytree, `loss_grad(cfg, data, x, precision)`: per-client f (m,)
+and ∇f (m, n) at the flat x, with r the configuration's `lipschitz`.
+It may also give `init(cfg, seed)`, the flat x⁰ both sides start from
+(otherwise 0, the paper's start), and `counts(cfg)` (bench/counting.py).
 
 Nothing here imports `repro` or takes anything the program made: float32
 `jnp`, every product at `Precision.HIGHEST`.
@@ -17,14 +24,17 @@ from __future__ import annotations
 import importlib
 import os
 import re
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 HIGHEST = jax.lax.Precision.HIGHEST
 PRECISIONS = ("highest", "high")
+ROW_BLOCK = 1 << 15
 _NAME = re.compile(r"^[A-Za-z0-9_]+$")
 
 
@@ -36,6 +46,24 @@ def load(kind: str, name: str):
         raise ValueError(f"bench/{kind}/{name}.py does not exist: the "
                          f"benchmark cannot build {kind} {name!r}")
     return importlib.import_module(f"bench.{kind}.{name}")
+
+
+def by_rows(fn, *arrays):
+    """`fn` over blocks of ROW_BLOCK rows of the host `arrays` (of one
+    length), on the host's cores, the blocks' results joined by rows.
+    Row for row the numbers `fn` gives over the whole arrays; at 10^6
+    rows and more in a fraction of the time, and with float64
+    temporaries of a block, not of the whole."""
+    starts = range(0, len(arrays[0]), ROW_BLOCK)
+    with ThreadPoolExecutor(min(len(starts), os.cpu_count() or 1)) as ex:
+        parts = list(ex.map(
+            lambda s: fn(*(a[s:s + ROW_BLOCK] for a in arrays)), starts))
+    return np.concatenate(parts)
+
+
+def own_gradient(cfg: dict):
+    """The problem's own `loss_grad`, or None for the linear form."""
+    return getattr(load("losses", cfg["problem"]), "loss_grad", None)
 
 
 def _bf16(a):
@@ -72,22 +100,47 @@ def scale_rows(a, s, precision: str):
 
 
 def lipschitz(cfg: dict, data: dict) -> float:
-    """r = max_i of client i's Hessian bound, in float64 on the host."""
-    A = np.asarray(data["A"], np.float64)
-    mask = np.asarray(data["mask"], np.float64)
-    Am = A * mask[:, :, None]
-    d = np.maximum(mask.sum(axis=1), 1.0)
+    """r = max_i of client i's Hessian bound, in float64 on the host; for
+    a problem with its own `loss_grad`, the configuration's `lipschitz`."""
+    if own_gradient(cfg):
+        return float(cfg["lipschitz"])
+    def masked(a, k):
+        k = np.asarray(k, np.float64)
+        return np.asarray(a, np.float64) * k[:, :, None]
+
+    A, mask = np.asarray(data["A"]), np.asarray(data["mask"])
+    d = np.maximum(np.asarray(mask, np.float64).sum(axis=1), 1.0)
     if A.shape[1] == 1:
-        top = np.sum(Am[:, 0, :] ** 2, axis=1)
+        top = by_rows(lambda a, k: np.sum(masked(a, k)[:, 0, :] ** 2, axis=1),
+                      A, mask)
     else:  # the largest eigenvalue of A_i A_iᵀ is that of A_iᵀ A_i
+        Am = masked(A, mask)
         top = np.linalg.eigvalsh(np.einsum("mdn,men->mde", Am, Am))[:, -1]
     return load("losses", cfg["problem"]).lipschitz(cfg, top, d)
 
 
-def loss_grad(cfg: dict, A, b, mask, x, precision: str):
-    """Per-client f_i(x) (m,) and ∇f_i(x) (m, n); A (m, d, n) or, with one
-    sample per client, (m, n)."""
+def device_data(cfg: dict, data: dict, mesh=None) -> dict:
+    """The data as the reference reads it, on the device: for the linear
+    form (A, b, mask) as `host_arrays` gives them, else the arrays as
+    made. Where the cell lies over a mesh, laid by rows over its first
+    axis, as the program's clients are, so that the whole population
+    fits."""
+    arrays = dict(data) if own_gradient(cfg) else \
+        dict(zip(("A", "b", "mask"), host_arrays(data)))
+    if mesh is None:
+        return {k: jnp.asarray(v) for k, v in arrays.items()}
+    rows = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
+    return {k: jax.device_put(np.asarray(v), rows) for k, v in arrays.items()}
+
+
+def loss_grad(cfg: dict, data: dict, x, precision: str):
+    """Per-client f_i(x) (m,) and ∇f_i(x) (m, n) over `device_data`; for
+    the linear form A is (m, d, n) or, with one sample per client, (m, n)."""
+    own = own_gradient(cfg)
+    if own:
+        return own(cfg, data, x, precision)
     loss = load("losses", cfg["problem"])
+    A, b, mask = data["A"], data["b"], data["mask"]
     single = A.ndim == 2
     z = product("mn,n->m" if single else "mdn,n->md", A, x, precision)
     d = jnp.maximum(mask, 1.0) if single else \

@@ -17,6 +17,10 @@ closed form: the ADMM branch runs the paper's k0 iterations of eqs.
 with σ = σ_t r / m and r from `bench/losses/<problem>.py`, worked out on
 the host in float64. Reported per round at x̄: f̄ = mean_i f_i(x̄),
 ‖(1/m) Σ_i g_i‖² and |C|.
+
+x, z, π and H are flat: a model's parameters in the order of their
+pytree's leaves, each leaf raveled. Where the cell lies over a mesh, the
+same program runs with its data laid by rows over it.
 """
 from __future__ import annotations
 
@@ -30,20 +34,28 @@ EMA_BETA = 0.9
 H_POLICIES = ("diag_ema",)
 
 
+def _flat(tree, lead: int):
+    """A parameter pytree raveled leaf by leaf, after `lead` leading axes;
+    a single leaf is only reshaped (for one (m, n) leaf: itself)."""
+    leaves = [l.reshape(l.shape[:lead] + (-1,)) for l in jax.tree.leaves(tree)]
+    return leaves[0] if len(leaves) == 1 else jnp.concatenate(leaves, -1)
+
+
 def program_state(state) -> dict:
     """The program's state after a call, under the reference's names: x̄
-    (n,) and the per-client rows z, π, H (m, n). Indexing only."""
-    out = {"x": state["x"]["x"]}
+    (n,) and the per-client rows z, π, H (m, n)."""
+    out = {"x": _flat(state["x"], 0)}
     for k in ("z", "pi", "h"):
-        out[k] = state[k]["x"]
+        out[k] = _flat(state[k], 1)
     return out
 
 
 def run(cfg: dict, data: dict, rounds: int, selection: dict,
-        precision: str = "highest") -> dict:
-    """`rounds` rounds from the paper's start (x = z = π = 0, H = r I).
-    Returns host arrays: per-round "f_xbar", "grad_sq_norm", "selected",
-    and the state after the last round, "x" (n,), "z", "pi", "h" (m, n)."""
+        precision: str = "highest", mesh=None, x0=None) -> dict:
+    """`rounds` rounds from x = z = x⁰ (the paper's 0 where `x0` is
+    None), π = 0, H = r I. Returns host arrays: per-round "f_xbar",
+    "grad_sq_norm", "selected", and the state after the last round, "x"
+    (n,), "z", "pi", "h" (m, n)."""
     if precision not in ref.PRECISIONS:
         raise ValueError(f"precision {precision!r} not in {ref.PRECISIONS}")
     if cfg["h_policy"] not in H_POLICIES:
@@ -57,7 +69,7 @@ def run(cfg: dict, data: dict, rounds: int, selection: dict,
     def one_round(data_d, r, sigma, carry, t):
         z, pi, h, key = carry
         xbar = jnp.sum(z, axis=0) / m
-        f, g = ref.loss_grad(cfg, *data_d, xbar, precision)
+        f, g = ref.loss_grad(cfg, data_d, xbar, precision)
         gb = g / m
         sel, key = next_mask(key, t)
         D = 1.0 / (h / m + sigma)
@@ -83,16 +95,19 @@ def run(cfg: dict, data: dict, rounds: int, selection: dict,
     # the data, and a program that held it would compile anew for every
     # seed whose data differ
     @jax.jit
-    def scan(data_d, key, r, sigma):
+    def scan(data_d, key, r, sigma, x0):
         zeros = jnp.zeros((m, n), jnp.float32)
-        carry = (zeros, zeros, jnp.full((m, n), r, jnp.float32), key)
+        z0 = zeros if x0 is None else jnp.broadcast_to(x0, (m, n))
+        carry = (z0, zeros, jnp.full((m, n), r, jnp.float32), key)
         (z, pi, h, _), (met, xbars) = jax.lax.scan(
             lambda c, t: one_round(data_d, r, sigma, c, t), carry,
             jnp.arange(rounds))
         return z, pi, h, met, xbars[-1]
 
-    data_d = tuple(jnp.asarray(v) for v in ref.host_arrays(data))
-    z, pi, h, (f, gsq, sel), x = scan(data_d, key0, r, sigma)
+    data_d = ref.device_data(cfg, data, mesh)
+    if x0 is not None:
+        x0 = jnp.asarray(x0, jnp.float32)
+    z, pi, h, (f, gsq, sel), x = scan(data_d, key0, r, sigma, x0)
     del data_d
     out = {"f_xbar": f, "grad_sq_norm": gsq, "selected": sel,
            "x": x, "z": z, "pi": pi, "h": h}

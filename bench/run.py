@@ -10,6 +10,8 @@ the problem from the seed, makes one `run_rounds` call exactly as the
 window will (that call is the one checked against the plain reference),
 then times whole calls for `--seconds` seconds; with `--trace 1` it
 traces `trace_calls` calls instead and reports the per-layer metrics.
+A cell on several chips lays its clients by rows over a `data` mesh of
+them (`bench/workload.py`), and its reference runs over the same mesh.
 The last line of stdout is one JSON object; the numbers compared, each
 with its limit, are the last lines of stderr and the last key of that
 object. A run that finds no TPU exits non-zero and prints no result.
@@ -144,7 +146,7 @@ def first_call(spec: Spec, cell: dict, seed: int) -> tuple:
 
     cfg = spec.config(cell)
     data = workload.make_data(cfg, seed)
-    problem = workload.build(cfg, data, seed)
+    problem = workload.build(cfg, data, seed, workload.layout(cell["chips"]))
     caller = workload.Caller(problem, spec.traffic(cell))
     return cfg, data, problem, caller, caller.call(problem.state0)
 
@@ -206,6 +208,7 @@ def run_cell(spec: Spec, cell: dict, seed: int, seconds: float, trace: bool,
         reading = tr.Reading(trace_data, chips=len(devs), rounds=rounds,
                              calls=calls, peaks=peaks,
                              kernels=cfg.get("kernels", {}),
+                             collectives=cfg.get("collectives"),
                              **counting.for_config(cfg))
         readers = {m["name"]: spec.reader(m["name"])
                    for m in spec.metrics("per_layer", cell)}
@@ -215,11 +218,13 @@ def run_cell(spec: Spec, cell: dict, seed: int, seconds: float, trace: bool,
     c1, h1 = counter.snapshot()
     peak = (peak_fn or device.peak_bytes)(devs)
     e2e["peak_hbm_bytes"] = peak
+    mesh = problem.mesh
     workload.free(problem.batch, problem.state0)
     del problem, caller
     gc.collect()
 
-    ref = workload.reference_outputs(cfg, data, seed, prog["rounds_run"])
+    ref = workload.reference_outputs(cfg, data, seed, prog["rounds_run"],
+                                     mesh=mesh)
     nums = compare.numbers(prog, ref, limits["grad_floor"])
     correct = compare.verdict(nums, limits)
 

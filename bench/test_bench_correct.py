@@ -21,8 +21,10 @@ from bench import compare, run, workload
 SMALL = {
     "fedgia_xdev_1m": dict(num_clients=2048, samples=2048, alpha=0.01),
     "fedgia_paper_v1": dict(num_clients=16, dim=64, samples=800),
+    "fedgia_xdev_8m": dict(num_clients=4096, samples=4096, alpha=0.01),
 }
-CELLS = ("xdev_1m.rounds", "paper_v1.solve")
+CELLS = ("xdev_1m.rounds", "paper_v1.solve")  # one chip: whole runs here
+ALL_CELLS = CELLS + ("xdev_8m.shard4",)  # four chips: test_bench_mesh.py
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -46,7 +48,7 @@ def one_run(spec, name, seed=2**31 + 11):
                         peak_fn=lambda devs: 1)
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", ALL_CELLS)
 def test_control_fails_the_limits(spec, name):
     cell = spec.cell(name)
     cfg, limits = spec.config(cell), spec.limits(cell)
@@ -114,7 +116,7 @@ def test_broken_timed_path_is_not_correct(spec, name, fault, monkeypatch):
 
 def test_limits_files_name_every_number():
     here = os.path.dirname(os.path.abspath(__file__))
-    for name in CELLS:
+    for name in ALL_CELLS:
         with open(os.path.join(here, "limits", name + ".json")) as f:
             lim = json.load(f)
         assert lim["selected_gap"] == 0
@@ -169,3 +171,20 @@ def test_a_given_lipschitz_bound_is_the_largest_row_norm():
     a = np.asarray(data["A"][:, 0, :], np.float64)
     want = np.float32(np.max(np.sum(a * a, axis=1)))
     assert workload.lipschitz_bound(cfg, data) == pytest.approx(float(want))
+
+
+def test_row_blocks_read_what_the_whole_arrays_read():
+    from bench import reference
+
+    rng = np.random.default_rng(4)
+    m = 3 * reference.ROW_BLOCK + 5
+    ref = rng.standard_normal((m, 7)).astype(np.float32)
+    prog = ref + 1e-5 * rng.standard_normal((m, 7)).astype(np.float32)
+    p64, r64 = np.asarray(prog, np.float64), np.asarray(ref, np.float64)
+    diff = np.linalg.norm(p64 - r64, axis=1)
+    norm = np.linalg.norm(r64, axis=1)
+    want = np.max(diff / np.maximum(norm, np.median(norm)))
+    assert compare.row_gap(prog, ref) == want
+    data = {"A": ref[:, None, :], "mask": np.ones((m, 1), np.float32)}
+    top = np.sum(r64 ** 2, axis=1)
+    assert reference.lipschitz({"problem": "linreg"}, data) == np.max(top)

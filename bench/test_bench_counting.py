@@ -29,7 +29,8 @@ def test_round_flops_by_hand():
 
 
 def test_for_config_uses_real_sizes():
-    cfg = {"num_clients": 128, "dim": 1024, "samples": 8992, "k0": 5}
+    cfg = {"num_clients": 128, "dim": 1024, "samples": 8992, "k0": 5,
+           "problem": "linreg"}
     got = counting.for_config(cfg)
     assert got["flops_per_round"] == 4 * 1024 * 8992 + 128 * 1024 * 26
     assert got["kernel_bytes_per_round"] == 7 * 128 * 4096 + 128 * 4 + 8

@@ -3,6 +3,7 @@ and on a small trace recorded on a TPU v5e."""
 import gzip
 import json
 import os
+import re
 
 import pytest
 
@@ -93,6 +94,34 @@ def test_readers_of_a_kernel_the_path_does_not_run_return_nothing():
         1e3 * 420e-9 / 4)
 
 
+def test_collectives_by_hand():
+    r = tr.Reading(_hand_trace(), chips=1, rounds=4, calls=2,
+                   flops_per_round=1e6, kernel_bytes_per_round=1e4,
+                   peaks=PEAKS, kernels=KERNELS, collectives=r"^all-reduce")
+    spec = run.Spec()
+    assert r.collective_s() == pytest.approx(20e-9)
+    assert spec.reader("collective_ms").read(r) == pytest.approx(
+        1e3 * 20e-9 / 4)
+    # outside the kernel and the collective: the four fusions
+    assert spec.reader("xla_ops_ms").read(r) == pytest.approx(
+        1e3 * 400e-9 / 4)
+    # one chip names no collective: nothing to read, xla_ops_ms as before
+    r1 = _reading(_hand_trace())
+    assert r1.collective_s() is None
+    assert spec.reader("collective_ms").read(r1) is None
+    assert spec.reader("xla_ops_ms").read(r1) == pytest.approx(
+        1e3 * 420e-9 / 4)
+
+
+def test_collectives_named_but_missing_from_the_trace_is_an_error():
+    t = _hand_trace()
+    t.devices[0] = [e for e in t.devices[0] if "all-reduce" not in e[0]]
+    with pytest.raises(ValueError, match="no collective op"):
+        tr.Reading(t, chips=1, rounds=4, calls=2, flops_per_round=1.0,
+                   kernel_bytes_per_round=1.0, peaks=PEAKS, kernels=KERNELS,
+                   collectives=r"^all-reduce")
+
+
 def test_a_kernel_the_path_runs_missing_from_the_trace_is_an_error():
     t = _hand_trace()
     t.devices[0] = [e for e in t.devices[0] if "kernel" not in e[0]]
@@ -152,3 +181,39 @@ def test_a_trace_recorded_on_a_v5e():
     ops = [n for n, _ in r.breakdown()["device_ops"]]
     assert ops[0].startswith("fedgia_update_batched_kernel")
     assert not any(tr.CONTAINER.match(n) for n in ops)
+
+
+def test_a_four_chip_trace_recorded_on_a_v5e():
+    # one 16-round call of xdev_8m.shard4 (8*10^6 clients over 4 chips):
+    # eq. (11)'s all-reduce is named for its primitive (`psum.44 = ...
+    # all-reduce(...)`), as are the diagonal-H max (`pmax`) and the
+    # metrics' scalars (`all-reduce`)
+    with gzip.open(os.path.join(HERE, "traces", "xdev_8m.shard4.json.gz"),
+                   "rt") as f:
+        rec = json.load(f)
+    assert any(" all-reduce(" in raw for raw in rec["raw_names"])
+    trace = tr.Trace({int(k): [tuple(e) for e in v]
+                      for k, v in rec["devices"].items()},
+                     [tuple(e) for e in rec["spans"]],
+                     [tuple(e) for e in rec["host"]])
+    spec = run.Spec()
+    cell = spec.cell("xdev_8m.shard4")
+    cfg = spec.config(cell)
+    from bench import counting
+
+    r = tr.Reading(trace, chips=4, rounds=16, calls=1, peaks=PEAKS,
+                   kernels=cfg["kernels"], collectives=cfg["collectives"],
+                   **counting.for_config(cfg))
+    pattern = re.compile(cfg["collectives"])
+    named = {n for ops in r.chip_ops for n, _, _ in ops if pattern.search(n)}
+    assert {n.split(".")[0] for n in named} == {"psum", "pmax", "all-reduce"}
+    got = tr.collect(r, {m["name"]: spec.reader(m["name"])
+                         for m in spec.metrics("per_layer", cell)})
+    assert set(got) == {m["name"] for m in spec.metrics("per_layer", cell)}
+    assert 0 < got["collective_ms"] < got["fedgia_update_ms"]
+    assert 0 < got["fedgia_update_roofline"] < 100
+    assert 0 < got["round_mfu"] < 100
+    assert got["xla_ops_ms"] > got["fedgia_update_ms"] > 0
+    busy_ms = 1e3 * r.busy_s / 16
+    assert got["xla_ops_ms"] + got["fedgia_update_ms"] + \
+        got["collective_ms"] == pytest.approx(busy_ms, rel=1e-6)

@@ -144,6 +144,9 @@ class Reading:
     # the kernels the cell's path runs: name -> pattern of their op names
     # (the configuration's `kernels`)
     kernels: Dict[str, str] = dataclasses.field(default_factory=dict)
+    # the pattern of the op names of the collectives between the cell's
+    # chips (the configuration's `collectives`), None on one chip
+    collectives: Optional[str] = None
 
     def __post_init__(self):
         if not self.trace.spans:
@@ -163,6 +166,13 @@ class Reading:
                     f"(pattern {self.kernels[name]!r}) that the cell's "
                     f"path runs: its op names have changed, or the kernel "
                     f"left the path")
+        self._collectives = (None if self.collectives is None
+                             else re.compile(self.collectives))
+        if self._collectives is not None and not self.collective_s():
+            raise ValueError(
+                f"the trace holds no collective op (pattern "
+                f"{self.collectives!r}) though the cell's chips exchange "
+                f"eq. (11): its op names have changed")
 
     @property
     def window_s(self) -> float:
@@ -190,6 +200,26 @@ class Reading:
         else:
             return None
         return self.mean_ns(
+            lambda ops: matching_ns(ops, pattern, self.lo, self.hi)) * 1e-9
+
+    def collective_s(self) -> Optional[float]:
+        """Device seconds of the collectives, mean over chips; None where
+        the configuration names none."""
+        if self._collectives is None:
+            return None
+        return self.mean_ns(lambda ops: matching_ns(
+            ops, self._collectives, self.lo, self.hi)) * 1e-9
+
+    def outside_s(self) -> float:
+        """Device busy seconds outside the named kernels and collectives,
+        mean over chips."""
+        named = list(self.kernels.values())
+        if self.collectives is not None:
+            named.append(self.collectives)
+        if not named:
+            return self.busy_s
+        pattern = re.compile("|".join(f"(?:{p})" for p in named))
+        return self.busy_s - self.mean_ns(
             lambda ops: matching_ns(ops, pattern, self.lo, self.hi)) * 1e-9
 
     def idle_in_spans_s(self) -> float:
