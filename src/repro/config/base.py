@@ -43,6 +43,13 @@ class ModelConfig:
     dense_residual: bool = False  # arctic: dense MLP in parallel with MoE
     first_dense_layers: int = 0  # deepseek-v3: leading dense layers
     router_aux_coef: float = 0.0
+    # expert share (expert parallelism): this chip holds `experts_held`
+    # experts, ids first_expert .. first_expert + experts_held - 1, routes
+    # every token over all `num_experts` and computes, without dropping a
+    # token, its own experts' part of the layer (models/moe.py). 0 = all
+    # experts held, the capacity-dropping layer.
+    experts_held: int = 0
+    first_expert: int = 0
 
     # --- MLA (deepseek-v3) ---
     attention_type: str = "gqa"
@@ -57,7 +64,22 @@ class ModelConfig:
     rwkv_head_size: int = 64
 
     # --- long-context policy ---
-    sliding_window: int = 8192  # used ONLY when long_context mode is on
+    # serving's long-context mode; with `layer_types` also the width of
+    # the "window" layers' band (a key is visible iff 0 <= q - k < width)
+    sliding_window: int = 8192
+
+    # --- per-layer attention pattern (mellum2) ---
+    # one period of layer kinds, "window" or "full", repeated over the
+    # depth and run as one scanned period; () = every layer full causal.
+    # Window layers use plain RoPE at rope_theta; full layers YaRN when
+    # yarn_factor > 0 (models/layers.yarn_inv_freq).
+    layer_types: Tuple[str, ...] = ()
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
+    attn_block: int = 512  # query/key block of the banded attention
 
     # --- multi-token prediction aux head (deepseek-v3) ---
     mtp: bool = False
@@ -84,6 +106,22 @@ class ModelConfig:
             f"{self.name}: num_heads={self.num_heads} not divisible by "
             f"num_kv_heads={self.num_kv_heads}"
         )
+        assert set(self.layer_types) <= {"window", "full"}, self.layer_types
+        assert not self.layer_types or self.num_layers % len(self.layer_types) == 0, (
+            f"{self.name}: {self.num_layers} layers are no whole number of "
+            f"periods of {self.layer_types}"
+        )
+        assert self.experts_held == 0 or (
+            self.first_expert + self.experts_held <= self.num_experts), (
+            f"{self.name}: experts {self.first_expert}.."
+            f"{self.first_expert + self.experts_held - 1} are not among "
+            f"{self.num_experts}"
+        )
+
+    @property
+    def local_experts(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.experts_held or self.num_experts
 
     # ---------------------------------------------------------------- reduced
     def reduced(self) -> "ModelConfig":
@@ -115,6 +153,14 @@ class ModelConfig:
                 moe_d_ff=min(self.moe_d_ff, 256),
                 first_dense_layers=min(self.first_dense_layers, 1),
             )
+            if self.experts_held:
+                changes.update(experts_held=changes["num_experts"],
+                               first_expert=0)
+        if self.layer_types:
+            # one window and one full layer: both kinds in two layers
+            changes.update(layer_types=(self.layer_types[0],
+                                        self.layer_types[-1]),
+                           attn_block=8)
         if self.attention_type == "mla":
             changes.update(
                 q_lora_rank=min(self.q_lora_rank, 64),
@@ -163,7 +209,7 @@ class ModelConfig:
         total += dense_layers * dense_mlp
         if self.moe:
             total += moe_layers * (
-                self.num_experts * per_expert
+                self.local_experts * per_expert
                 + self.num_shared_experts * per_expert
                 + d * self.num_experts  # router
                 + (dense_mlp if self.dense_residual else 0)
@@ -178,7 +224,7 @@ class ModelConfig:
         per_expert = 3 * d * self.moe_d_ff
         moe_layers = self.num_layers - self.first_dense_layers
         inactive = moe_layers * per_expert * (
-            self.num_experts - self.experts_per_token
+            self.local_experts - min(self.experts_per_token, self.local_experts)
         )
         return int(self.param_count() - inactive)
 

@@ -16,6 +16,7 @@ from repro.configs.llava_next_mistral_7b import CONFIG as _llava
 from repro.configs.deepseek_67b import CONFIG as _ds67
 from repro.configs.hymba_15b import CONFIG as _hymba
 from repro.configs.deepseek_v3_671b import CONFIG as _dsv3
+from repro.configs.mellum2_12b import CONFIG as _mellum2
 
 ARCHITECTURES = {
     c.name: c
@@ -30,6 +31,7 @@ ARCHITECTURES = {
         _ds67,
         _hymba,
         _dsv3,
+        _mellum2,
     ]
 }
 

@@ -380,6 +380,23 @@ def shard_inputs(algo, state, batch, mesh, client_axis: str = "data"):
     )
 
 
+def _device_buffers(leaf) -> set:
+    return {shard.data.unsafe_buffer_pointer()
+            for shard in leaf.addressable_shards}
+
+
+def _release(tree, keep) -> None:
+    """Delete the device buffers of `tree`'s arrays that no array of
+    `keep` shares (a placement that moves nothing returns a new array
+    over the same buffers)."""
+    arrays = lambda t: [l for l in jax.tree.leaves(t)
+                        if isinstance(l, jax.Array) and not l.is_deleted()]
+    held = set().union(*[_device_buffers(l) for l in arrays(keep)])
+    for leaf in arrays(tree):
+        if not _device_buffers(leaf) & held:
+            leaf.delete()
+
+
 # ------------------------------------------------------------------ driver
 AUTO_CHUNK_CANDIDATES = (8, 32, 128)
 
@@ -421,6 +438,7 @@ def run_rounds(
     checkpoint_every: int = 0,
     checkpoint_dir: Optional[str] = None,
     resume: bool = False,
+    consume: bool = False,
 ) -> RoundResult:
     """Run up to `num_rounds` communication rounds of `algo`.
 
@@ -625,6 +643,14 @@ def run_rounds(
     (num_rounds is excluded — extending a finished run is the point).
     Supported on the chunked scan driver and the host-driven offload
     loop; rejected with chunk_size="auto" and under a mesh.
+
+    consume: the call takes the caller's state over: once the engine
+    holds its own (flat) buffers the caller's are deleted, and the carry
+    is donated without the protective copy `donate` makes otherwise.
+    Leaves the engine keeps as they are (scalars, the rng) are donated
+    with the carry. A state of several GB a chip then needs one copy on
+    the device at a time, not three (caller's, flat, copy). The caller
+    must not read the state it passed in again.
 
     donate_kernel: donate the flat (m, N) state buffers into the Pallas
     `fedgia_update` kernel (`input_output_aliases` + XLA donation), so
@@ -882,6 +908,7 @@ def run_rounds(
                 faults=faults, screening=screening, quorum=quorum,
                 watchdog=bool(watchdog), watchdog_patience=watchdog_patience,
                 watchdog_factor=watchdog_factor)
+        caller_state = state
         spec = pt.ravel_spec(state["x"]) if flat else None
         if flat:
             # the ONE ravel of the run: everything downstream carries the
@@ -921,6 +948,9 @@ def run_rounds(
                                      faults=faults, screening=screening)
         if mesh is not None:
             state, batch = shard_inputs(algo, state, batch, mesh, client_axis)
+        if consume:
+            _release(caller_state, keep=state)
+        del caller_state
         if donate is None:
             # CPU XLA cannot alias buffers; donating would only emit warnings
             donate = jax.default_backend() != "cpu"
@@ -1038,7 +1068,7 @@ def run_rounds(
             return jax.lax.scan(step, carry, None, length=length)
 
         donate_args = (0,) if donate else ()
-        if donate:
+        if donate and not consume:
             # donation must never consume the CALLER's buffers (states are
             # routinely reused across run_rounds calls, e.g. scan-vs-loop
             # comparisons); copy once up front so every donated carry after

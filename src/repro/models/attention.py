@@ -9,13 +9,14 @@ the Pallas flash kernel in repro/kernels/flash_attention.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
-from repro.models.layers import apply_rope, he_init
+from repro.models.layers import apply_rope, he_init, yarn_inv_freq
 
 NEG_INF = -1e30
 
@@ -95,6 +96,152 @@ def blocked_attention(q, k, v, q_positions, kv_positions, *, window=None,
     return out.astype(q.dtype)
 
 
+# ============================================ banded causal attention
+# Training-mode attention over one sequence with no cache: causal, and
+# within `window` keys where a window is given (a key is visible iff
+# 0 <= q - k < window). Queries and keys go in blocks of `block`; query
+# block i visits only key blocks lo(i)..i, the ones its causal band can
+# see, in the forward pass and in the gradient, which is written out
+# (FlashAttention-2's: scores recomputed per block pair, from the
+# saved log-sum-exp) rather than taken through the loops. Products take
+# the inputs' dtype and accumulate in float32; the softmax is float32.
+
+
+def _first_key_block(i, block: int, window: Optional[int]):
+    if window is None:
+        return jnp.zeros_like(i)
+    return jnp.maximum(0, (i * block - window + 1) // block)
+
+
+def _visible(i, j, block: int, window: Optional[int]):
+    """(block, block) mask of query block i against key block j."""
+    r = jnp.arange(block)
+    d = (i * block + r)[:, None] - (j * block + r)[None, :]
+    ok = d >= 0
+    if window is not None:
+        ok &= d < window
+    return ok
+
+
+def _to_blocks(q, k, v, block: int):
+    """q (B,S,H,D) -> (nb,B,Kv,G,b,D); k, v (B,S,Kv,D) -> (nb,B,Kv,b,D),
+    S padded up to a whole number of blocks."""
+    B, S, H, D = q.shape
+    Kv = k.shape[2]
+    nb = -(-S // block)
+    pad = ((0, 0), (0, nb * block - S), (0, 0), (0, 0))
+    q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
+    qb = q.reshape(B, nb, block, Kv, H // Kv, D).transpose(1, 0, 3, 4, 2, 5)
+    kb = k.reshape(B, nb, block, Kv, D).transpose(1, 0, 3, 2, 4)
+    vb = v.reshape(B, nb, block, Kv, D).transpose(1, 0, 3, 2, 4)
+    return qb, kb, vb
+
+
+def _from_blocks(ob, S: int):
+    """(nb,B,Kv,G,b,D) -> (B,S,H,D)."""
+    nb, B, Kv, G, b, D = ob.shape
+    out = ob.transpose(1, 0, 4, 2, 3, 5).reshape(B, nb * b, Kv * G, D)
+    return out[:, :S]
+
+
+def _scores(qi, kj, scale):
+    return jnp.einsum("bkgqd,bktd->bkgqt", qi, kj,
+                      preferred_element_type=jnp.float32) * scale
+
+
+def _banded_fwd_impl(q, k, v, window, block, scale):
+    qb, kb, vb = _to_blocks(q, k, v, block)
+    nb = qb.shape[0]
+
+    def one(args):
+        i, qi = args
+
+        def body(j, carry):
+            m, l, acc = carry
+            s = _scores(qi, kb[j], scale)
+            ok = _visible(i, j, block, window)
+            s = jnp.where(ok, s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+            corr = jnp.exp(m - m_new)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bkgqt,bktd->bkgqd", p.astype(v.dtype), vb[j],
+                preferred_element_type=jnp.float32)
+            return m_new, l * corr + p.sum(-1), acc
+
+        stat = qi.shape[:-1]
+        m, l, acc = jax.lax.fori_loop(
+            _first_key_block(i, block, window), i + 1, body,
+            (jnp.full(stat, NEG_INF, jnp.float32),
+             jnp.zeros(stat, jnp.float32),
+             jnp.zeros(qi.shape, jnp.float32)))
+        return (acc / l[..., None]).astype(q.dtype), m + jnp.log(l)
+
+    ob, lse = jax.lax.map(one, (jnp.arange(nb), qb))
+    return ob, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def banded_attention(q, k, v, window: Optional[int], block: int,
+                     scale: float):
+    """q: (B,S,H,D); k, v: (B,S,Kv,D), H a multiple of Kv (GQA); the
+    positions are 0..S-1. Returns (B,S,H,D) in q's dtype."""
+    ob, _ = _banded_fwd_impl(q, k, v, window, block, scale)
+    return _from_blocks(ob, q.shape[1])
+
+
+def _banded_fwd(q, k, v, window, block, scale):
+    ob, lse = _banded_fwd_impl(q, k, v, window, block, scale)
+    return _from_blocks(ob, q.shape[1]), (q, k, v, ob, lse)
+
+
+def _banded_bwd(window, block, scale, res, dout):
+    q, k, v, ob, lse = res
+    S = q.shape[1]
+    qb, kb, vb = _to_blocks(q, k, v, block)
+    dob, _, _ = _to_blocks(dout, k, v, block)
+    delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32), -1)
+    nb = qb.shape[0]
+
+    def one(carry, xs):
+        i, qi, doi, lsei, di = xs
+
+        def body(j, c):
+            dq, dk, dv = c
+            kj, vj = kb[j], vb[j]
+            ok = _visible(i, j, block, window)
+            p = jnp.where(ok, jnp.exp(_scores(qi, kj, scale)
+                                      - lsei[..., None]), 0.0)
+            dp = jnp.einsum("bkgqd,bktd->bkgqt", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - di[..., None])).astype(q.dtype)
+            dq = dq + scale * jnp.einsum("bkgqt,bktd->bkgqd", ds, kj,
+                                         preferred_element_type=jnp.float32)
+            dk = dk.at[j].add(scale * jnp.einsum(
+                "bkgqt,bkgqd->bktd", ds, qi,
+                preferred_element_type=jnp.float32))
+            dv = dv.at[j].add(jnp.einsum(
+                "bkgqt,bkgqd->bktd", p.astype(v.dtype), doi,
+                preferred_element_type=jnp.float32))
+            return dq, dk, dv
+
+        dq, dk, dv = jax.lax.fori_loop(
+            _first_key_block(i, block, window), i + 1, body,
+            (jnp.zeros(qi.shape, jnp.float32),) + carry)
+        return (dk, dv), dq.astype(q.dtype)
+
+    zeros = jnp.zeros(kb.shape, jnp.float32)
+    (dk, dv), dqb = jax.lax.scan(one, (zeros, zeros),
+                                 (jnp.arange(nb), qb, dob, lse, delta))
+    B, Kv = k.shape[0], k.shape[2]
+    unblock = lambda a: a.transpose(1, 0, 3, 2, 4).reshape(
+        B, -1, Kv, a.shape[-1])[:, :S].astype(k.dtype)
+    return _from_blocks(dqb, S), unblock(dk), unblock(dv)
+
+
+banded_attention.defvjp(_banded_fwd, _banded_bwd)
+
+
 # ===================================================================== GQA
 def gqa_init(rng, cfg: ModelConfig, dtype):
     d, H, Kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -140,8 +287,14 @@ def _write_cache(cache, k_new, v_new, positions):
     return cache
 
 
-def gqa_apply(params, cfg: ModelConfig, x, positions, cache, mode: AttnMode):
-    """x: (B,S,d); positions: (S,) int32. Returns (out, new_cache)."""
+def gqa_apply(params, cfg: ModelConfig, x, positions, cache, mode: AttnMode,
+              kind: Optional[str] = None):
+    """x: (B,S,d); positions: (S,) int32. Returns (out, new_cache).
+
+    `kind` ("window" | "full") is the layer's place in `cfg.layer_types`:
+    window layers see `cfg.sliding_window` keys with plain RoPE, full
+    layers every earlier key with YaRN's RoPE where `cfg.yarn_factor` is
+    set; both run `banded_attention` (training only, positions 0..S-1)."""
     B, S, d = x.shape
     H, Kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = jnp.einsum("bsd,de->bse", x, params["wq"])
@@ -152,6 +305,9 @@ def gqa_apply(params, cfg: ModelConfig, x, positions, cache, mode: AttnMode):
     q = q.reshape(B, S, H, hd)
     k = k.reshape(B, S, Kv, hd)
     v = v.reshape(B, S, Kv, hd)
+    if kind is not None:
+        return _patterned_attention(params, cfg, q, k, v, positions, mode,
+                                    kind), cache
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
 
@@ -180,6 +336,28 @@ def gqa_apply(params, cfg: ModelConfig, x, positions, cache, mode: AttnMode):
         )
     out = out.reshape(B, S, H * hd)
     return jnp.einsum("bse,ed->bsd", out, params["wo"]), new_cache
+
+
+def _patterned_attention(params, cfg: ModelConfig, q, k, v, positions,
+                         mode: AttnMode, kind: str):
+    if mode.kind != "train":
+        raise NotImplementedError(
+            f"{cfg.name}: layers of a `layer_types` pattern run training "
+            f"only, not {mode.kind!r}")
+    B, S, H, hd = q.shape
+    inv_freq, scale = None, 1.0
+    if kind == "full" and cfg.yarn_factor:
+        inv_freq = yarn_inv_freq(hd, cfg.rope_theta, cfg.yarn_factor,
+                                 cfg.yarn_original_max_position,
+                                 cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+        scale = cfg.yarn_attention_factor
+    q = apply_rope(q, positions, cfg.rope_theta, inv_freq, scale)
+    k = apply_rope(k, positions, cfg.rope_theta, inv_freq, scale)
+    window = cfg.sliding_window if kind == "window" else None
+    with jax.named_scope(f"attn.{kind}"):
+        out = banded_attention(q, k, v, window, cfg.attn_block,
+                               1.0 / hd ** 0.5)
+    return jnp.einsum("bse,ed->bsd", out.reshape(B, S, H * hd), params["wo"])
 
 
 # ===================================================================== MLA

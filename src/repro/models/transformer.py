@@ -149,7 +149,8 @@ class Transformer:
         return out
 
     # ----------------------------------------------------------------- apply
-    def _block_apply(self, kind: str, params, x, cache, positions, mode: AttnMode):
+    def _block_apply(self, kind: str, params, x, cache, positions, mode: AttnMode,
+                     attn_kind: Optional[str] = None):
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         if kind == "rwkv":
@@ -183,7 +184,8 @@ class Transformer:
             )
         else:
             h, attn_cache_new = attn_lib.gqa_apply(
-                params["attn"], cfg, xn, positions, attn_cache, mode
+                params["attn"], cfg, xn, positions, attn_cache, mode,
+                kind=attn_kind,
             )
         if kind == "hybrid":
             ssm_state = (
@@ -197,7 +199,9 @@ class Transformer:
 
         xn = rmsnorm(params["norm2"], x, cfg.norm_eps)
         if kind == "moe":
-            h, aux = moe_lib.moe_apply(params["moe"], cfg, xn)
+            moe_apply = (moe_lib.moe_share_apply if cfg.experts_held
+                         else moe_lib.moe_apply)
+            h, aux = moe_apply(params["moe"], cfg, xn)
             if cfg.dense_residual:
                 h = h + mlp_apply(params["mlp"], xn)
         else:
@@ -212,7 +216,38 @@ class Transformer:
             new_cache = attn_cache_new
         return x, new_cache, aux
 
+    def _run_periods(self, group: LayerGroup, params, x, positions, mode):
+        """`cfg.layer_types`: the group's layers as periods of that
+        pattern, one scan step a period, each layer its own remat."""
+        cfg = self.cfg
+        P = len(cfg.layer_types)
+
+        def period(carry, p):
+            x, aux = carry
+            for i, attn_kind in enumerate(cfg.layer_types):
+                def layer(x, p_i, attn_kind=attn_kind):
+                    x, _, a = self._block_apply(group.kind, p_i, x, {},
+                                                positions, mode, attn_kind)
+                    return x, a
+                if cfg.remat and mode.kind == "train":
+                    layer = jax.checkpoint(layer)
+                x, a = layer(x, jax.tree.map(lambda l: l[i], p))
+                aux = aux + a
+            return (x, aux), None
+
+        stacked = jax.tree.map(
+            lambda l: l.reshape((group.count // P, P) + l.shape[1:]), params)
+        carry = (x, jnp.zeros((), jnp.float32))
+        if cfg.scan_layers:
+            carry, _ = jax.lax.scan(period, carry, stacked)
+        else:
+            for n in range(group.count // P):
+                carry, _ = period(carry, jax.tree.map(lambda l: l[n], stacked))
+        return carry[0], {}, carry[1]
+
     def _run_group(self, group: LayerGroup, params, x, cache, positions, mode):
+        if self.cfg.layer_types:
+            return self._run_periods(group, params, x, positions, mode)
         if not self.cfg.scan_layers:
             # straight-line layers (dry-run cost pass: scan bodies are
             # counted once by XLA cost_analysis, so unroll for accounting)

@@ -84,7 +84,7 @@ def test_checkpoint_roundtrip(tmp_path):
 
 
 def test_all_architectures_registered():
-    assert len(list_architectures()) == 10
+    assert len(list_architectures()) == 11
     families = {get_config(a).family for a in list_architectures()}
     assert families == {"dense", "moe", "ssm", "hybrid", "vlm", "audio"}
 
@@ -94,6 +94,7 @@ def test_param_counts_near_nameplate():
         "arctic-480b": 480e9, "deepseek-v3-671b": 671e9, "deepseek-67b": 67e9,
         "stablelm-12b": 12e9, "llava-next-mistral-7b": 7.2e9,
         "tinyllama-1.1b": 1.1e9, "qwen1.5-0.5b": 0.46e9,
+        "mellum2-12b-a2.5b": 12e9,
     }
     for arch, target in expect.items():
         got = get_config(arch).param_count()

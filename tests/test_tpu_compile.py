@@ -173,3 +173,32 @@ def test_reduce_scatter_compiles_to_all_reduce(topo, n):
     assert collective_counts(got) == {
         "all-reduce": 1, "reduce-scatter": 0, "all-gather": 0}
     assert f"f32[{n}]" in got and "dynamic-slice(%all-reduce" in got
+
+
+def test_expert_share_compiles_to_named_ragged_dots(one_chip):
+    """Mellum2's expert share at its real widths (hidden 2,304, experts of
+    896, 8 of 64 held, top-8, 16,384 tokens), forward and gradient, under
+    the engine's per-client vmap: the grouped products compile to the
+    TPU's ragged-dot kernels, whose names the `moe_experts` pattern of
+    bench/configs/fedgia_mellum2.json reads."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import moe
+
+    cfg = dataclasses.replace(get_config("mellum2-12b-a2.5b"),
+                              experts_held=8, first_expert=8)
+    params = jax.eval_shape(lambda: moe.moe_init(jax.random.PRNGKey(0), cfg,
+                                                 jnp.bfloat16))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    x = place(jax.ShapeDtypeStruct((1, 2, 8192, cfg.d_model), jnp.bfloat16))
+    loss = lambda p, x: jnp.sum(
+        moe.moe_share_apply(p, cfg, x)[0].astype(jnp.float32))
+    grad = jax.vmap(jax.grad(loss), in_axes=(None, 0))
+    txt = jax.jit(grad).lower(jax.tree.map(place, params), x).compile().as_text()
+    names = re.findall(r"%(ragged-dot[\w.-]*) = ", txt)
+    # the backward's six grouped products (each product's input and weight
+    # gradients) and the forward's, less what the compiler merges
+    assert sum(n.startswith("ragged-dot-none") for n in names) >= 6
